@@ -1,16 +1,11 @@
-"""Shared demo setup: import path + a fast backend.
+"""Shared demo setup: the import path.
 
-The demos default to CPU so they run anywhere instantly; delete the
-``jax_platforms`` line to run on real TPU hardware (first compile takes
-tens of seconds there, then flushes are sub-millisecond).
+The demos run on whatever JAX finds; set ``JAX_PLATFORMS=cpu`` to run
+them on the host CPU (first compile on a TPU takes tens of seconds, then
+flushes are sub-millisecond).
 """
 
 import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
-import jax
-
-if not os.environ.get("SENTINEL_DEMO_REAL_DEVICES"):
-    jax.config.update("jax_platforms", "cpu")

@@ -3,8 +3,8 @@ over an 8-device mesh, budgets conserved across chips with ICI
 collectives instead of a token-server RPC (the TPU-native replacement
 for sentinel-demo-cluster's server deployment).
 
-Runs on a virtual 8-device CPU mesh out of the box; on an 8-chip TPU
-slice set SENTINEL_DEMO_REAL_DEVICES=1.
+With JAX_PLATFORMS=cpu it runs on a virtual 8-device CPU mesh; on a TPU
+it needs 8 chips.
 """
 
 import os

@@ -217,7 +217,11 @@ def engine_main(handles: PlaneHandles, overrides, setup, setup_args) -> None:
     config.set(config.IPC_ENABLED, "false")
     from sentinel_tpu.core import api
     from sentinel_tpu.ipc.plane import IngestPlane
+    from sentinel_tpu.utils.backend import enable_compile_cache, require_platform
     from sentinel_tpu.utils.record_log import record_log
+
+    require_platform()
+    enable_compile_cache()
 
     stop = threading.Event()
 
@@ -229,13 +233,9 @@ def engine_main(handles: PlaneHandles, overrides, setup, setup_args) -> None:
 
     eng = api.get_engine()
     if setup is not None:
-        try:
-            setup(eng, *(setup_args or ()))
-        except Exception:
-            record_log.error(
-                "[supervise] engine setup failed — serving without it",
-                exc_info=True,
-            )
+        # A failed setup fails the child (non-zero exit): the watcher's
+        # restart path owns the retry, never a rule-less engine.
+        setup(eng, *(setup_args or ()))
     if eng.failover.armed and eng.failover.durable_path:
         try:
             eng.failover.restore_durable()
@@ -353,7 +353,11 @@ def standby_main(
     config.set(config.CAPTURE_ENABLED, "false")
     from sentinel_tpu.core import api
     from sentinel_tpu.ipc.plane import IngestPlane
+    from sentinel_tpu.utils.backend import enable_compile_cache, require_platform
     from sentinel_tpu.utils.record_log import record_log
+
+    require_platform()
+    enable_compile_cache()
 
     stop = threading.Event()
 
@@ -366,13 +370,7 @@ def standby_main(
     t0 = time.perf_counter()
     eng = api.get_engine()
     if setup is not None:
-        try:
-            setup(eng, *(setup_args or ()))
-        except Exception:
-            record_log.error(
-                "[standby] engine setup failed — serving without it",
-                exc_info=True,
-            )
+        setup(eng, *(setup_args or ()))
     warm_s = max(
         0.05,
         config.get_int(config.SUPERVISE_STANDBY_WARM_MS, 2000) / 1e3,
@@ -389,16 +387,11 @@ def standby_main(
                 )
 
     _rewarm()
-    try:
-        # The restore path may have probed already (try_recover); this
-        # guarantees the jit cache is populated even when failover is
-        # unarmed or the durable file does not exist yet.
-        eng.failover.warm_probe()
-    except Exception:
-        record_log.error(
-            "[standby] warm probe failed — reporting ready anyway "
-            "(first flush will compile)", exc_info=True,
-        )
+    # The restore path may have probed already (try_recover); this
+    # guarantees the jit cache is populated even when failover is
+    # unarmed or the durable file does not exist yet. A failed probe
+    # fails the child: a standby that never compiled is not warm.
+    eng.failover.warm_probe()
     warm_boot_ms = (time.perf_counter() - t0) * 1e3
     try:
         conn.send(("ready", warm_boot_ms))
@@ -465,6 +458,18 @@ class EngineSupervisor:
     ) -> None:
         from sentinel_tpu.datasource.backoff import Backoff
 
+        self.standby_enabled = config.get_bool(config.SUPERVISE_STANDBY, False)
+        if self.standby_enabled:
+            from sentinel_tpu.utils.backend import expects_tpu
+
+            if expects_tpu():
+                raise RuntimeError(
+                    "sentinel.tpu.supervise.standby.enabled=true on a TPU "
+                    "host: the warm standby is a second engine process, and "
+                    "a chip serves one process at a time, so the standby "
+                    "cannot hold the chip while the primary lives. Disable "
+                    "the standby here."
+                )
         self._ctx = multiprocessing.get_context("spawn")
         if prefix is None:
             prefix = (config.get(config.IPC_SHM_PREFIX) or "").strip()
@@ -497,7 +502,6 @@ class EngineSupervisor:
         # pre-forked, compile-warmed engine child parked off-plane; on
         # primary death (or planned handoff) it attaches instead of a
         # cold respawn, and the NEXT standby is pre-forked immediately.
-        self.standby_enabled = config.get_bool(config.SUPERVISE_STANDBY, False)
         self.standby_takeovers = 0
         self.handoffs = 0
         self.standby_warm_boot_ms: Optional[float] = None

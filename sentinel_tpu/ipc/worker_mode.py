@@ -241,8 +241,15 @@ def client_entry(
 
 def worker_main(channel, worker_id: int, overrides, target, args) -> object:
     """Spawn bootstrap (top-level so ``multiprocessing`` spawn children
-    import it by name): replay the parent's runtime config, arm worker
-    mode, attach, run ``target(worker_id, *args)``, detach."""
+    import it by name): pin the CPU, replay the parent's runtime config,
+    arm worker mode, attach, run ``target(worker_id, *args)``, detach.
+
+    A worker needs no device — the engine process holds the chip, and a
+    chip serves one process at a time — so the pin comes first, before
+    anything can start a backend."""
+    from sentinel_tpu.utils.backend import force_cpu
+
+    force_cpu()
     for k, v in (overrides or {}).items():
         config.set(k, v)
     config.set(config.IPC_WORKER_MODE, "true")

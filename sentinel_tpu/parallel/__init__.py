@@ -21,21 +21,11 @@ from sentinel_tpu.parallel.ici import (
 
 
 def mesh_unavailable_reason(n_devices: int = 2) -> Optional[str]:
-    """Why the sharded flush path cannot run in this environment, or
-    None when it can. The sharded kernels are written against the
-    stable ``jax.shard_map`` / ``jax.lax.axis_size`` API surface; on an
-    older jax (or with too few devices) the capability is absent and
-    callers — tests above all — should skip with this reason instead
-    of failing on an ImportError deep inside a kernel trace."""
+    """Why the sharded flush path cannot run in this environment (too few
+    devices), or None when it can — callers, tests above all, skip with
+    this reason."""
     import jax
 
-    if not hasattr(jax, "shard_map"):
-        return (
-            f"jax {jax.__version__} has no stable jax.shard_map "
-            "(the sharded kernels require it)"
-        )
-    if not hasattr(jax.lax, "axis_size"):
-        return f"jax {jax.__version__} lacks jax.lax.axis_size"
     if len(jax.devices()) < n_devices:
         return (
             f"needs a {n_devices}-device mesh, environment has "

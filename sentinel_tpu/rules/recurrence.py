@@ -79,8 +79,8 @@ def run_segmented(
 
         # Large counts: a fori_loop compiles the round body ONCE.
         # Unrolling 16+ copies of the transition into the HLO multiplied
-        # remote-compile time past the bench's stage timeout (round-4
-        # hardware session) for runtime that is identical.
+        # compile time past the bench's stage timeout (round-4 hardware
+        # session) for runtime that is identical.
         n_st = len(seg_states)
 
         def body(r, carry):

@@ -23,7 +23,10 @@ Four pieces:
   device→host fetch run on a waiter thread bounded by
   ``sentinel.tpu.failover.fetch.timeout.ms`` — a wedged
   ``jax.device_get`` times out and trips failover instead of stranding
-  the pipeline (and every submitter behind the flush lock) forever;
+  the pipeline (and every submitter behind the flush lock) forever.
+  Time the waiter spends in JAX's first-use trace/lower/compile does
+  not count against that bound (a cold compile is not a device fault);
+  it has its own, ``COMPILE_TIMEOUT_MS``;
 * a :class:`HostFallbackAdmitter` serving admission while DEGRADED from
   the already-compiled rule tables: host token buckets for QPS flow
   rules, live concurrency counters for THREAD rules, last-known breaker
@@ -1179,6 +1182,71 @@ class HostFallbackAdmitter:
             }
 
 
+class _CompileClock:
+    """Seconds each thread has spent in JAX's first-use work (trace,
+    lowering, XLA compile), read from ``jax.monitoring``: JAX reports
+    each span's start as a scalar and its end as a duration, both on
+    the thread doing the work. One per process (listeners are global)."""
+
+    EVENTS = frozenset((
+        "/jax/core/compile/jaxpr_trace_duration",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration",
+        "/jax/core/compile/backend_compile_duration",
+    ))
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._open: Dict[int, List[float]] = {}  # ident -> [depth, start]
+        self._spent: Dict[int, float] = {}
+        jax.monitoring.register_scalar_listener(self._on_start)
+        jax.monitoring.register_event_duration_secs_listener(self._on_end)
+
+    def _on_start(self, event: str, _value, **_kw) -> None:
+        if event in self.EVENTS:
+            with self._lock:
+                span = self._open.setdefault(
+                    threading.get_ident(), [0, time.monotonic()]
+                )
+                span[0] += 1
+
+    def _on_end(self, event: str, _secs, **_kw) -> None:
+        if event not in self.EVENTS:
+            return
+        ident = threading.get_ident()
+        with self._lock:
+            span = self._open.get(ident)
+            if span is None:
+                return
+            span[0] -= 1
+            if span[0] == 0:
+                del self._open[ident]
+                self._spent[ident] = (
+                    self._spent.get(ident, 0.0) + time.monotonic() - span[1]
+                )
+
+    def seconds(self, ident: Optional[int]) -> float:
+        """Compile seconds of thread ``ident`` so far, an open span
+        included."""
+        with self._lock:
+            s = self._spent.get(ident, 0.0)
+            span = self._open.get(ident)
+            if span is not None:
+                s += time.monotonic() - span[1]
+        return s
+
+
+_COMPILE_CLOCK: Optional[_CompileClock] = None
+_COMPILE_CLOCK_LOCK = threading.Lock()
+
+
+def compile_clock() -> _CompileClock:
+    global _COMPILE_CLOCK
+    with _COMPILE_CLOCK_LOCK:
+        if _COMPILE_CLOCK is None:
+            _COMPILE_CLOCK = _CompileClock()
+        return _COMPILE_CLOCK
+
+
 class _Waiter:
     """One persistent watchdog waiter thread: the engine submits a
     device call to it and waits with a timeout, so a wedged
@@ -1191,6 +1259,10 @@ class _Waiter:
     slow call's queueing can never count against another's timeout."""
 
     __slots__ = ("lost", "_jobs", "_thread")
+
+    @property
+    def ident(self) -> Optional[int]:
+        return self._thread.ident
 
     def __init__(self, name: str) -> None:
         import queue
@@ -1302,6 +1374,7 @@ class FailoverManager:
         # an abandoned restore's install is gated on holding the
         # current generation — see _restore_locked.
         self._restore_gen = 0
+        self._compiles = compile_clock()
 
     # ------------------------------------------------------------------
     # state machine
@@ -1451,30 +1524,40 @@ class FailoverManager:
     # ------------------------------------------------------------------
     # watchdog
     # ------------------------------------------------------------------
-    # A kernel DISPATCH includes first-use XLA compilation, which
-    # legitimately takes many seconds cold — a dispatch bound tied
-    # directly to the fetch timeout would false-trip on every new jit
-    # signature. Dispatch therefore gets this floor under its bound.
-    DISPATCH_TIMEOUT_FLOOR_MS = 60_000
+    # A kernel DISPATCH includes first-use XLA compilation, which takes
+    # a minute or more cold at full batch on a v5e: the bound counts only
+    # the waiter's time outside compiles, and a compile gets this one.
+    COMPILE_TIMEOUT_MS = 600_000
 
     def watched(self, fn, what: str, seqs: Sequence[int],
                 timeout_ms: Optional[int] = None):
         """Run ``fn`` on the persistent watchdog waiter thread bounded
-        by the fetch timeout. A wedged device call cannot be cancelled,
-        only abandoned: on timeout the waiter is marked lost (it parks
-        on the dead call forever, daemonic) and the next watched call
-        lazily starts a replacement."""
+        by the fetch timeout, not counting the waiter's time in JAX
+        compiles. A wedged device call cannot be cancelled, only
+        abandoned: on timeout the waiter is marked lost (it parks on the
+        dead call forever, daemonic) and the next watched call lazily
+        starts a replacement."""
         if timeout_ms is None:
             timeout_ms = self.fetch_timeout_ms
-            if "dispatch" in what:
-                timeout_ms = max(timeout_ms, self.DISPATCH_TIMEOUT_FLOOR_MS)
         with self._waiter_lock:
             w = self._idle_waiters.pop() if self._idle_waiters else None
         if w is None or w.lost:
             w = _Waiter("sentinel-failover-waiter")
+        clock = self._compiles
+        c0 = clock.seconds(w.ident)
+        t0 = time.monotonic()
         box, done = w.submit(fn)
+        limit_s = timeout_ms / 1000.0
+        cap_s = self.COMPILE_TIMEOUT_MS / 1000.0
         try:
-            if not done.wait(timeout_ms / 1000.0):
+            left_s = min(limit_s, cap_s)
+            while not done.wait(left_s):
+                compile_s = clock.seconds(w.ident) - c0
+                outside_left = limit_s - (time.monotonic() - t0 - compile_s)
+                compile_left = cap_s - compile_s
+                if outside_left > 0 and compile_left > 0:
+                    left_s = max(min(outside_left, compile_left), 0.01)
+                    continue
                 w.lost = True
                 # Also queue the stop sentinel: if the wedged call
                 # finishes after this flag but before its own lost
@@ -1483,7 +1566,8 @@ class FailoverManager:
                 w.stop()
                 raise DeviceFetchTimeout(
                     f"{what} exceeded {timeout_ms} ms"
-                    f" (flush seqs {list(seqs)})"
+                    f" ({compile_s:.3f} s of it compiling;"
+                    f" flush seqs {list(seqs)})"
                 )
         finally:
             if not w.lost:

@@ -158,7 +158,8 @@ class SentinelConfig:
     FAILOVER_ENABLED = "sentinel.tpu.failover.enabled"
     # Watchdog bound on kernel dispatch and the device->host fetch: a
     # wedged jax.device_get times out (on a waiter thread) and trips
-    # the engine DEGRADED instead of stranding submitters forever.
+    # the engine DEGRADED instead of stranding submitters forever. Time
+    # the waiter spends compiling is not counted against it.
     FAILOVER_FETCH_TIMEOUT_MS = "sentinel.tpu.failover.fetch.timeout.ms"
     # Per-resource fail-open/fail-closed while DEGRADED: "open" |
     # "closed" | "open,resA=closed,..." (first '='-less segment is the

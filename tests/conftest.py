@@ -17,6 +17,12 @@ os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_cpu_parallel_codegen_split_count=1"
 ).strip()
 
+# The persistent compilation cache stays off for the whole tier (see
+# _bound_jit_state below), in spawned children too: the program turns it
+# on in its entry points (utils/backend.enable_compile_cache), and this
+# variable, which children inherit, overrides that.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+
 from sentinel_tpu.utils.backend import force_cpu
 
 force_cpu(8)
@@ -26,6 +32,8 @@ import signal  # noqa: E402
 
 import jax  # noqa: E402
 import pytest  # noqa: E402
+
+jax.config.update("jax_enable_compilation_cache", False)
 
 # Hard wall-clock bound for one `mp`-marked test: generous against the
 # 1-core box's spawn+import cost (each worker process re-imports jax),
@@ -110,11 +118,10 @@ def engine(manual_clock):
 
 
 def pytest_collection_modifyitems(config, items):
-    """Auto-skip ``mesh``-marked tests when the sharded flush
-    capability is absent (parallel.mesh_unavailable_reason: older jax
-    without stable jax.shard_map, or too few devices): a capability
-    the environment lacks is a skip with a reason, not a wall of
-    ImportError failures hiding real regressions."""
+    """Auto-skip ``mesh``-marked tests when the environment has too
+    few devices for the sharded flush (parallel.mesh_unavailable_reason):
+    a skip with a reason, not a wall of failures hiding real
+    regressions."""
     from sentinel_tpu.parallel import mesh_unavailable_reason
 
     reason = mesh_unavailable_reason(8)

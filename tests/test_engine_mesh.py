@@ -373,10 +373,8 @@ class TestEngineMesh:
             assert adm_o <= 10
 
 class TestMeshLifecycle:
-    """Capability-independent mesh API edges: enable/disable plumbing
-    that never builds a sharded kernel, so these run (and must keep
-    passing) even where ``jax.shard_map`` is absent — deliberately NOT
-    ``mesh``-marked."""
+    """Mesh API edges: enable/disable plumbing that never builds a
+    sharded kernel — deliberately NOT ``mesh``-marked."""
 
     def test_non_pow2_mesh_rejected(self, manual_clock, engine):
         with pytest.raises(ValueError, match="power of two"):
@@ -394,3 +392,25 @@ class TestMeshLifecycle:
         op = mesh_engine.submit_entry("s")
         mesh_engine.flush()
         assert op.verdict.admitted
+
+    @pytest.mark.mesh
+    def test_disable_mesh_puts_tables_and_state_on_one_device(
+        self, mesh_engine
+    ):
+        import jax
+
+        import sentinel_tpu as st
+
+        mesh_engine.set_flow_rules([st.FlowRule("s", count=10)])
+        mesh_engine.submit_entry("s")
+        mesh_engine.flush()  # state now written by a sharded flush
+        mesh_engine.disable_mesh()
+        op = mesh_engine.submit_entry("s")
+        mesh_engine.flush()
+        assert op.verdict.admitted
+        trees = (mesh_engine.flow_index.device, mesh_engine.degrade_index.device,
+                 mesh_engine.stats, mesh_engine.flow_dyn,
+                 mesh_engine.degrade_dyn, mesh_engine.param_dyn)
+        devs = {d for leaf in jax.tree_util.tree_leaves(trees)
+                for d in leaf.sharding.device_set}
+        assert devs == {jax.devices()[0]}

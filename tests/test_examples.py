@@ -28,7 +28,7 @@ def test_example_runs_clean(demo):
         if reason:
             pytest.skip(reason)
     env = dict(os.environ)
-    env.pop("SENTINEL_DEMO_REAL_DEVICES", None)  # force the CPU path
+    env["JAX_PLATFORMS"] = "cpu"
     env["SENTINEL_DEMO_PORT"] = "0"  # ephemeral ports: no collisions
     env["SENTINEL_DEMO_DURATION"] = "2"  # shorten long traffic loops
     r = subprocess.run(

@@ -661,6 +661,71 @@ class TestEngineLifecycle:
         assert not victim.closed_dirty
 
 
+class TestCompileAwareWatchdog:
+    """The watchdog's bound counts a waiter's time outside JAX compiles:
+    a cold compile (a minute or more at full batch on a v5e) is not a
+    device fault."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def _compiling(self, seconds):
+        import jax
+
+        def fn():
+            jax.monitoring.record_scalar(self.EVENT, time.time())
+            time.sleep(seconds)
+            jax.monitoring.record_event_duration_secs(self.EVENT, seconds)
+            return 7
+
+        return fn
+
+    def test_compile_span_is_not_counted(self, manual_clock):
+        eng = _mk_engine(manual_clock, enabled=True)
+        out = eng.failover.watched(self._compiling(0.5), "kernel dispatch",
+                                   (1,), timeout_ms=100)
+        assert out == 7
+        assert eng.failover.state == "HEALTHY"
+
+    def test_wait_outside_compile_trips(self, manual_clock):
+        from sentinel_tpu.runtime.failover import DeviceFetchTimeout
+
+        eng = _mk_engine(manual_clock, enabled=True)
+        with pytest.raises(DeviceFetchTimeout, match="exceeded 100 ms"):
+            eng.failover.watched(lambda: time.sleep(0.5), "kernel dispatch",
+                                 (1,), timeout_ms=100)
+
+    def test_compile_bound_still_trips(self, manual_clock, monkeypatch):
+        from sentinel_tpu.runtime.failover import (
+            DeviceFetchTimeout, FailoverManager,
+        )
+
+        monkeypatch.setattr(FailoverManager, "COMPILE_TIMEOUT_MS", 150)
+        eng = _mk_engine(manual_clock, enabled=True)
+        with pytest.raises(DeviceFetchTimeout, match="compiling"):
+            eng.failover.watched(self._compiling(0.6), "kernel dispatch",
+                                 (1,), timeout_ms=5000)
+
+    def test_clock_sees_real_compiles_on_their_thread(self):
+        import jax
+        import jax.numpy as jnp
+
+        from sentinel_tpu.runtime.failover import compile_clock
+
+        clock = compile_clock()
+        seen = {}
+
+        def work():
+            ident = threading.get_ident()
+            seen["before"] = clock.seconds(ident)
+            jax.jit(lambda x: jnp.sin(x) * 3.25 + 0.125)(jnp.ones(7))
+            seen["after"] = clock.seconds(ident)
+
+        t = threading.Thread(target=work)
+        t.start()
+        t.join()
+        assert seen["after"] > seen["before"]
+
+
 @pytest.mark.slow
 class TestChaosSoak:
     def test_random_fault_soak_depth4(self, manual_clock):

@@ -122,8 +122,7 @@ def test_soak_concurrent_engine(manual_clock, engine):
         # the first half — every enable_mesh builds fresh shard_map
         # closures whose pjit compiles legitimately grow the executable
         # cache, and the steady-state RSS check below must measure
-        # flushing, not compiles. Toggles are capability-gated: without
-        # jax.shard_map the soak still exercises everything else.
+        # flushing, not compiles. Toggles need an 8-device mesh.
         from sentinel_tpu.parallel import mesh_unavailable_reason
 
         mesh_ok = mesh_unavailable_reason(8) is None

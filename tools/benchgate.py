@@ -28,7 +28,7 @@ Usage::
     python bench.py --gate                    # bench + gate in one go
     python bench.py > fresh.json
     python tools/benchgate.py --fresh fresh.json [--repo-root .]
-                              [--baseline BENCH_r05.json]
+                              [--baseline BENCH_r11.json]
                               [--tolerance-scale 1.0]
 
 Exit status: 0 pass or skip-with-reason, 1 regression (or a fresh

@@ -153,7 +153,10 @@ def _demo_cfg(spans_dir: str) -> dict:
 def _worker_child(channel, wid, cfg, n, q):
     """Spawned ingest worker: n entries + one bulk against the shared
     rings; the journal spills on close."""
+    from sentinel_tpu.utils.backend import force_cpu
     from sentinel_tpu.utils.config import config
+
+    force_cpu()  # the parent's engine holds the chip
 
     for k, v in cfg.items():
         config.set(k, v)
@@ -174,7 +177,10 @@ def _worker_child(channel, wid, cfg, n, q):
 def _shard_child(cfg, flow_ids, q, stop_evt):
     """Spawned token shard: a real TCP SentinelTokenServer with the
     demo's cluster flow rules loaded; journal spills on stop."""
+    from sentinel_tpu.utils.backend import force_cpu
     from sentinel_tpu.utils.config import config
+
+    force_cpu()  # the parent's engine holds the chip
 
     for k, v in cfg.items():
         config.set(k, v)
