@@ -168,7 +168,11 @@ def spans_to_trace(
 
     Layout: every span's ``encode`` and ``dispatch`` slices go on tid 1
     (``host``) — flush dispatches are serialized under the engine's
-    flush lock, so they never overlap. The dispatch→settle window of a
+    flush lock, so they never overlap. Inside ``encode`` sit its
+    ``staging``, ``shaping`` and ``param`` parts, in the order the
+    engine runs them (staging takes the encode time the other two do
+    not); ``fetch`` and ``fill`` follow from the settle start, where the
+    verdicts landed. The dispatch→settle window of a
     deferred flush (``inflight``: device execution + fetch latency)
     goes on the first free ``inflight-N`` tid (greedy interval
     assignment), so a depth-K pipeline shows K parallel tracks whose
@@ -208,8 +212,24 @@ def spans_to_trace(
             "inflight": s.inflight, "deferred": s.deferred,
         }
         tb.slice(pid, 1, "encode", enc_start, enc_dur, cat="flush", args=args)
+        sh_dur = s.shaping_encode_ms * 1e3
+        p_dur = s.param_encode_ms * 1e3
+        st_dur = max(enc_dur - sh_dur - p_dur, 0.0)
+        tb.slice(pid, 1, "staging", enc_start, st_dur, cat="flush", args=args)
+        tb.slice(pid, 1, "shaping", enc_start + st_dur, sh_dur, cat="flush",
+                 args=dict(args, items=s.shaping_items,
+                           padded=s.shaping_padded, rounds=s.shaping_rounds))
+        tb.slice(pid, 1, "param", enc_start + st_dur + sh_dur, p_dur,
+                 cat="flush", args=dict(args, items=s.param_items))
         tb.slice(pid, 1, "dispatch", disp_start, disp_dur, cat="flush",
                  args=args)
+        if s.settled and s.settle_t0 > 0.0:
+            fetch_start = us(s.settle_t0)
+            fetch_dur = s.fetch_ms * 1e3
+            tb.slice(pid, 1, "fetch", fetch_start, fetch_dur, cat="flush",
+                     args=args)
+            tb.slice(pid, 1, "fill", fetch_start + fetch_dur, s.fill_ms * 1e3,
+                     cat="flush", args=dict(args, groups=s.groups))
         dispatch_anchor[s.flush_id] = disp_start + disp_dur * 0.5
         if s.settled and s.settle_end > s.t0:
             fly_start = disp_start + disp_dur

@@ -10,10 +10,16 @@ module is the first-class telemetry layer:
 
 * a bounded ring-buffer **flight recorder** of structured per-flush
   spans (:class:`FlushSpan`) — flush id, pipeline depth and in-flight
-  occupancy at dispatch, batch rows, encode/dispatch/settle wall-ms,
+  occupancy at dispatch, batch rows, per-phase wall-ms (swap, encode
+  with its shaping and param parts, dispatch, fetch, verdict fill),
   arena and intern-cache hit/miss deltas, coalesced-fetch fallbacks —
   recorded by ``Engine._run_chunk`` with near-zero overhead and nothing
-  at all when disabled (``sentinel.tpu.telemetry.enabled``);
+  at all when disabled (``sentinel.tpu.telemetry.enabled``). The same
+  phase boundaries go on the profiler's clock: :meth:`TelemetryBus.phase`
+  opens a ``jax.profiler.TraceAnnotation`` named ``sentinel.<phase>``
+  (a no-op unless a profiler session is active) around the very code
+  whose milliseconds it writes onto the span, so a device trace and the
+  flight recorder read one set of boundaries;
 * fixed-bucket **latency histograms** (metrics/histogram.py) for
   host-blocking flush time, coalesced drain fetches and end-to-end
   admission (dispatch start → verdicts materialized) — tails, not
@@ -33,33 +39,33 @@ module is the first-class telemetry layer:
 The bus is engine-scoped (one per :class:`Engine`); the process-global
 engine's bus is therefore the process view. Config keys::
 
-    sentinel.tpu.telemetry.enabled      default true
+    sentinel.tpu.telemetry.enabled      default true; off, no FlushSpan
+                                        is recorded and
+                                        Engine.last_flush_host_ms reads
+                                        zeros (the profiler spans stay)
     sentinel.tpu.telemetry.ring         span ring capacity, default 4096
     sentinel.tpu.telemetry.blocked.topk.k
                                         device blocked top-K per flush,
-                                        default 8 (0 disables the fold);
-                                        falls back to the historical
-                                        sentinel.tpu.telemetry.sketch.k
+                                        default 8 (0 disables the fold)
     sentinel.tpu.telemetry.blocked.topk.capacity
-                                        host summary capacity, default
-                                        64; falls back to
-                                        sentinel.tpu.telemetry.sketch.capacity
+                                        host summary capacity, default 64
     sentinel.tpu.telemetry.topk.export  rows the exports list when the
                                         fold is off, default 10
 
-The ``blocked.*`` spelling landed with the statistics sketch tier
-(runtime/sketch.py, ``sentinel.tpu.sketch.*``) so the PR-3
-blocked-weight top-K and the count-min statistics tier stay
-distinguishable in code, config, and docs; ``TelemetryBus.sketch`` /
-``sketch_k`` remain as deprecated read aliases.
+The ``blocked.*`` spelling keeps the blocked-weight top-K and the
+count-min statistics sketch tier (runtime/sketch.py,
+``sentinel.tpu.sketch.*``) distinguishable in code, config, and docs.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from sentinel_tpu.metrics.histogram import LatencyHistogram
 from sentinel_tpu.utils.config import config
@@ -81,11 +87,26 @@ class FlushSpan:
     n_exits: int = 0  # single exit/trace ops
     n_bulk: int = 0  # bulk entry rows
     n_bulk_exits: int = 0  # bulk exit rows
-    encode_ms: float = 0.0
+    # Phase wall-ms, each the duration of the ``sentinel.<phase>``
+    # profiler span of the same name (TelemetryBus.phase).
+    swap_ms: float = 0.0  # buffer swap + rebase + re-resolve (first chunk)
+    encode_ms: float = 0.0  # staging + shaping + param encode
+    shaping_encode_ms: float = 0.0
+    param_encode_ms: float = 0.0
     dispatch_ms: float = 0.0
+    # Own device→host fetch (0 when a coalesced drain fetched this
+    # chunk's results: that time is in the drain histogram) and the
+    # verdict fill from the fetched arrays.
+    fetch_ms: float = 0.0
+    fill_ms: float = 0.0
     settle_t0: float = 0.0  # perf_counter when the result fetch began
     settle_end: float = 0.0  # perf_counter when verdicts materialized
-    settle_ms: float = 0.0  # device→host fetch duration (own or coalesced share)
+    settle_ms: float = 0.0  # fetch_ms + fill_ms
+    shaping_items: int = 0  # shaping (entry, slot) items encoded
+    shaping_padded: int = 0  # their padded batch length
+    shaping_rounds: int = 0  # pacer mode: -1 closed form, 0 scan, >0 rounds
+    param_items: int = 0  # hot-param items encoded
+    groups: int = 0  # bulk entry groups whose verdicts the fill fans out
     deferred: bool = False  # dispatched without fetching (pipelined/async)
     settled: bool = False
     arena_hits: int = 0
@@ -129,9 +150,19 @@ class FlushSpan:
             "n_exits": self.n_exits,
             "n_bulk": self.n_bulk,
             "n_bulk_exits": self.n_bulk_exits,
+            "swap_ms": round(self.swap_ms, 4),
             "encode_ms": round(self.encode_ms, 4),
+            "shaping_encode_ms": round(self.shaping_encode_ms, 4),
+            "param_encode_ms": round(self.param_encode_ms, 4),
             "dispatch_ms": round(self.dispatch_ms, 4),
+            "fetch_ms": round(self.fetch_ms, 4),
+            "fill_ms": round(self.fill_ms, 4),
             "settle_ms": round(self.settle_ms, 4),
+            "shaping_items": self.shaping_items,
+            "shaping_padded": self.shaping_padded,
+            "shaping_rounds": self.shaping_rounds,
+            "param_items": self.param_items,
+            "groups": self.groups,
             "deferred": self.deferred,
             "settled": self.settled,
             "arena_hits": self.arena_hits,
@@ -141,6 +172,42 @@ class FlushSpan:
             "fallbacks": self.fallbacks,
             "quarantined": self.quarantined,
         }
+
+
+class Phase:
+    """One engine phase on the profiler's clock and on the flight
+    recorder at once (built by :meth:`TelemetryBus.phase`). The
+    perf_counter reads sit just inside the TraceMe's own enter and exit,
+    so ``ms`` — written onto ``span.<field>`` when a span is given — is
+    the profiler span's duration. ``set`` appends args known only once
+    the phase has run (item counts, the rounds picked)."""
+
+    __slots__ = ("_tm", "_span", "_field", "_t0", "ms")
+
+    def __init__(self, tm, span: Optional[FlushSpan], field: Optional[str]):
+        self._tm = tm
+        self._span = span
+        self._field = field
+        self._t0 = 0.0
+        self.ms = 0.0
+
+    def __enter__(self) -> "Phase":
+        if self._tm is not None:
+            self._tm.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def set(self, **args) -> None:
+        if self._tm is not None:
+            self._tm.set_metadata(**args)
+
+    def __exit__(self, et, ev, tb) -> bool:
+        self.ms = (time.perf_counter() - self._t0) * 1e3
+        if self._span is not None:
+            setattr(self._span, self._field, self.ms)
+        if self._tm is not None:
+            self._tm.__exit__(et, ev, tb)
+        return False
 
 
 class SpaceSaving:
@@ -217,20 +284,23 @@ class TelemetryBus:
             if ring is not None
             else config.get_int(config.TELEMETRY_RING, 4096),
         )
-        # Blocked-weight top-K fold size (PR 3) — NOT the statistics
-        # sketch tier (sentinel.tpu.sketch.*, runtime/sketch.py). The
-        # ``blocked.topk.k`` spelling is preferred; the historical
-        # ``telemetry.sketch.k`` key is the fallback when unset.
-        if sketch_k is not None:
-            k = sketch_k
-        else:
-            k = config.get_int(config.TELEMETRY_BLOCKED_TOPK_K, -1)
-            if k < 0:
-                k = config.get_int(config.TELEMETRY_SKETCH_K, 8)
-        self.blocked_topk_k = max(0, k)
+        # Blocked-weight top-K fold size — NOT the statistics sketch
+        # tier (sentinel.tpu.sketch.*, runtime/sketch.py).
+        self.blocked_topk_k = max(
+            0,
+            sketch_k
+            if sketch_k is not None
+            else config.get_int(config.TELEMETRY_BLOCKED_TOPK_K, 8),
+        )
         self._spans: "deque[FlushSpan]" = deque(maxlen=self.ring_size)
         self._lock = threading.Lock()
         self._next_id = 0
+        # The newest non-empty flush: its chunks' spans, the swap time
+        # its first chunk's span takes, and the coalesced drain fetches
+        # that landed since it began (Engine.last_flush_host_ms).
+        self._flush_spans: List[FlushSpan] = []
+        self._swap_ms = 0.0
+        self._drain_ms = 0.0
         self.hist_flush = LatencyHistogram()
         self.hist_drain = LatencyHistogram()
         self.hist_e2e = LatencyHistogram()
@@ -328,13 +398,11 @@ class TelemetryBus:
         self.health_events: "deque[Tuple[int, str, str, str]]" = deque(
             maxlen=64
         )
-        if sketch_capacity is not None:
-            cap = sketch_capacity
-        else:
-            cap = config.get_int(config.TELEMETRY_BLOCKED_TOPK_CAP, -1)
-            if cap < 0:
-                cap = config.get_int(config.TELEMETRY_SKETCH_CAP, 64)
-        self.blocked_sketch = SpaceSaving(cap)
+        self.blocked_sketch = SpaceSaving(
+            sketch_capacity
+            if sketch_capacity is not None
+            else config.get_int(config.TELEMETRY_BLOCKED_TOPK_CAP, 64)
+        )
         # Most recent flush's device top-K, already name-resolved:
         # [(resource, blocked_weight)] — the "what is being throttled
         # right now" read, no extra host round-trip.
@@ -345,21 +413,6 @@ class TelemetryBus:
         # inserts evict the oldest past _SEC_CAP.
         self._sec: Dict[int, List[float]] = {}
         self._SEC_CAP = 600
-
-    # ------------------------------------------------------------------
-    # naming-compat aliases (PR-3 callers): ``sketch``/``sketch_k``
-    # predate the statistics sketch tier — the blocked-weight fold now
-    # lives under its own name so the two planes stay distinguishable.
-    # ------------------------------------------------------------------
-    @property
-    def sketch(self) -> SpaceSaving:
-        """Deprecated alias of :attr:`blocked_sketch`."""
-        return self.blocked_sketch
-
-    @property
-    def sketch_k(self) -> int:
-        """Deprecated alias of :attr:`blocked_topk_k`."""
-        return self.blocked_topk_k
 
     @property
     def export_topk_k(self) -> int:
@@ -374,6 +427,53 @@ class TelemetryBus:
     # ------------------------------------------------------------------
     # span lifecycle (engine hot path)
     # ------------------------------------------------------------------
+    def phase(
+        self, name: str, span: Optional[FlushSpan] = None,
+        field: Optional[str] = None, **args,
+    ) -> Phase:
+        """Context manager for one engine phase: a profiler span
+        ``name`` carrying ``args`` (built only while a profiler session
+        is active) and, when the bus is enabled and ``span`` is given,
+        the phase's milliseconds on ``span.<field>``."""
+        tm = TraceAnnotation(name, **args) if TraceAnnotation.is_enabled() else None
+        return Phase(tm, span if self.enabled else None, field)
+
+    @staticmethod
+    def flush_step(seq: int) -> Phase:
+        """The ``sentinel.flush`` profiler step around one
+        ``Engine.flush()`` call, numbered by the flush seq its first
+        chunk dispatches under."""
+        tm = (
+            StepTraceAnnotation("sentinel.flush", step_num=seq, flush=seq)
+            if TraceAnnotation.is_enabled()
+            else None
+        )
+        return Phase(tm, None, None)
+
+    def begin_flush(self, swap_ms: float) -> None:
+        """A non-empty flush swapped its buffers: it becomes the
+        newest flush of :meth:`last_flush_ms`, and its first chunk's
+        span takes ``swap_ms``."""
+        with self._lock:
+            self._flush_spans = []
+            self._swap_ms = swap_ms
+            self._drain_ms = 0.0
+
+    def last_flush_ms(self) -> Dict[str, float]:
+        """Host breakdown of the newest flush, summed over its chunks'
+        spans (see ``Engine.last_flush_host_ms``)."""
+        with self._lock:
+            spans = list(self._flush_spans)
+            drain = self._drain_ms
+        dispatch = sum(s.dispatch_ms for s in spans)
+        return {
+            "encode_ms": sum(s.encode_ms for s in spans),
+            "dispatch_ms": dispatch,
+            "kernel_ms": dispatch + sum(s.fetch_ms for s in spans),
+            "fill_ms": sum(s.fill_ms for s in spans),
+            "drain_ms": drain,
+        }
+
     def begin_span(
         self,
         t0: float,
@@ -393,8 +493,11 @@ class TelemetryBus:
                 flush_id=fid, t0=t0, depth=depth, inflight=inflight,
                 n_entries=n_entries, n_exits=n_exits, n_bulk=n_bulk,
                 n_bulk_exits=n_bulk_exits, deferred=deferred,
+                swap_ms=self._swap_ms,
             )
+            self._swap_ms = 0.0
             self._spans.append(span)
+            self._flush_spans.append(span)
             self.counters["flushes"] += 1
             if deferred:
                 self.counters["deferred_flushes"] += 1
@@ -421,7 +524,7 @@ class TelemetryBus:
         """Verdicts materialized: close the span, record histograms."""
         span.settle_t0 = settle_t0
         span.settle_end = end
-        span.settle_ms = max(0.0, (end - settle_t0) * 1e3)
+        span.settle_ms = span.fetch_ms + span.fill_ms
         span.settled = True
         if not span.deferred:
             self.hist_flush.record(span.host_ms)
@@ -437,6 +540,8 @@ class TelemetryBus:
 
     def note_drain(self, ms: float) -> None:
         self.hist_drain.record(ms)
+        with self._lock:
+            self._drain_ms += ms
 
     def note_fallback(self, n: int = 1) -> None:
         with self._lock:

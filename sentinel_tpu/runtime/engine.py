@@ -184,17 +184,20 @@ class _PendingFetch:
                     self._quarantine_locked(fo)
                     return
                 items: Optional[List[tuple]] = None
+                tele = self._engine.telemetry
                 t_fetch0 = time.perf_counter()
                 try:
                     if got is None:
                         t0 = time.perf_counter()
                         got = self._engine._fetch_refs(
-                            self._refs, (self._seq,)
+                            self._refs, (self._seq,), self._span
                         )
                         self._engine._note_drain_ms(
                             (time.perf_counter() - t0) * 1e3
                         )
-                    items = self._fill(got)
+                    with tele.phase("sentinel.fill", self._span, "fill_ms",
+                                    flush=self._seq, groups=len(self._bulk)):
+                        items = self._fill(got)
                 except BaseException as exc:
                     if fo.armed:
                         fo.trip("fetch", exc, self._seq)
@@ -224,9 +227,7 @@ class _PendingFetch:
                     # drain the fetch cost is in the drain histogram —
                     # settle_t0 here times only this record's own fill
                     # (plus its own fetch on the fallback path).
-                    self._engine.telemetry.settle(
-                        span, t_fetch0, time.perf_counter()
-                    )
+                    tele.settle(span, t_fetch0, time.perf_counter())
                 entries, self._entries = self._entries, []
                 self._bulk = []
                 self._exits = []
@@ -235,7 +236,8 @@ class _PendingFetch:
                     # Post-work failures (log IO, release RPCs) surface
                     # to this materializer only: the verdicts ARE
                     # filled, so readers must not see them as poisoned.
-                    self._engine._post_flush((entries, items or []))
+                    with tele.phase("sentinel.post_flush", flush=self._seq):
+                        self._engine._post_flush((entries, items or []))
             if self._error is not None:
                 raise self._error
 
@@ -707,22 +709,12 @@ class Engine:
         self._arena: Optional[_EncodeArena] = (
             _EncodeArena() if config.get_bool(config.HOST_FASTPATH, True) else None
         )
-        # Host-side breakdown of the most recent flush (diagnostics /
-        # bench attribution): encode_ms is staging-array build time,
-        # dispatch_ms the kernel dispatch alone, kernel_ms dispatch +
-        # device→host fetch, drain_ms the coalesced fetches of earlier
-        # in-flight flushes that landed while this breakdown was
-        # current. Swaps/increments under _timing_lock; readers get a
-        # snapshot via last_flush_host_ms.
-        self._timing_lock = threading.Lock()
-        self._flush_timing = {
-            "encode_ms": 0.0, "dispatch_ms": 0.0, "kernel_ms": 0.0,
-            "drain_ms": 0.0,
-        }
         # Engine flight recorder (metrics/telemetry.py): per-flush
-        # spans + histograms + blocked-resource top-K. When disabled,
-        # the hot path pays exactly one bool read per flush and the
-        # kernel blocked-weight fold compiles away (blk_topk=0).
+        # spans + histograms + blocked-resource top-K — the one
+        # per-flush record (last_flush_host_ms reads it). When
+        # disabled, no span is recorded and the kernel blocked-weight
+        # fold compiles away (blk_topk=0); the profiler phase spans
+        # stay.
         self.telemetry = TelemetryBus()
         self._blk_topk_k = (
             self.telemetry.blocked_topk_k if self.telemetry.enabled else 0
@@ -2005,124 +1997,131 @@ class Engine:
         """
         if not self.enabled:
             return None
-        if n < 1:
-            raise ValueError("submit_bulk: n must be >= 1")
-        if n > self.max_batch:
-            raise ValueError(
-                f"submit_bulk: n={n} exceeds max_batch={self.max_batch}; split the group"
-            )
-        if self.ingest.armed:
-            cause = self.ingest.check_bulk(n)
-            if cause is not None:
-                return self._shed_bulk(
-                    resource, n, context_name, origin, acquire, cause
-                )
-        sk = self.sketch
-        if sk.cold_armed and sk.cold_blocked(
-            resource, self.flow_index, self.param_index, n=n
-        ):
-            # Cold-key ceiling (runtime/sketch.py): covers both the
-            # over-cap and the routed-but-unconfigured group classes
-            # before any state is touched.
-            return self._blocked_cold_bulk(
-                resource, n, context_name, origin, acquire
-            )
-        if (
-            sk.cold_armed
-            and args_column is not None
-            and self.param_index.sketch_idx_by_resource
-        ):
-            # VALUE-grade cold ceiling over the group's args column: a
-            # fully-blocked group refuses dense (never enqueued); a
-            # PARTIALLY blocked group needs per-row verdicts, which is
-            # per-entry routing — decline like the other bulk-refusing
-            # rule classes so the columnar spine's ValueError fallback
-            # re-routes through submit_entry on the same flush.
-            vmask = sk.cold_value_mask(
-                resource, self.param_index, args_column, n
-            )
-            if vmask is not None:
-                if bool(vmask.all()):
-                    # Row-weighted, matching cold_blocked's bulk count.
-                    sk.note_cold_value_rows(n)
-                    return self._refused_bulk(
-                        resource, n, context_name, origin, acquire,
-                        reason=E.BLOCK_SKETCH, provenance="sketch_cold",
-                        count_shed=False,
-                    )
+        # The submit's own work is the ``sentinel.submit`` phase; the
+        # flush a full batch triggers runs after it, as its sibling.
+        seq = self._flush_seq + 1
+        with self.telemetry.phase("sentinel.submit", flush=seq, n=n):
+            if n < 1:
+                raise ValueError("submit_bulk: n must be >= 1")
+            if n > self.max_batch:
                 raise ValueError(
-                    "submit_bulk: sketch cold-value ceiling needs "
-                    "per-entry verdicts on this group — use submit_many"
+                    f"submit_bulk: n={n} exceeds max_batch={self.max_batch}; split the group"
                 )
-        with self._lock:
-            findex = self.flow_index
-            dindex = self.degrade_index
-            rows = self.resolve_entry_rows(resource, context_name, origin, entry_type)
-            if rows is None:
-                if self.sketch.armed:
-                    acq = self._bulk_col(acquire, n, 1)
-                    self.sketch.note_unrouted(resource, int(acq.sum()))
-                return None
-            slots = findex.resolve_slots(resource, context_name, origin, self.nodes)
-            if findex.cluster_gids and any(
-                gid in findex.cluster_gids for gid, _ in slots
+            if self.ingest.armed:
+                cause = self.ingest.check_bulk(n)
+                if cause is not None:
+                    return self._shed_bulk(
+                        resource, n, context_name, origin, acquire, cause
+                    )
+            sk = self.sketch
+            if sk.cold_armed and sk.cold_blocked(
+                resource, self.flow_index, self.param_index, n=n
             ):
-                raise ValueError(
-                    "submit_bulk: resource has cluster-mode flow rules "
-                    "(token verdicts are per entry) — use submit_many, "
-                    "which resolves them with one batched token RPC"
+                # Cold-key ceiling (runtime/sketch.py): covers both the
+                # over-cap and the routed-but-unconfigured group classes
+                # before any state is touched.
+                return self._blocked_cold_bulk(
+                    resource, n, context_name, origin, acquire
                 )
-            auth_ok = True
-            arule = self.authority_rules.get(resource)
-            if arule is not None:
-                from sentinel_tpu.rules.authority_manager import AuthorityRuleManager
-
-                auth_ok = AuthorityRuleManager.passes(arule, origin)
-            p_cols: List[_BulkParamCols] = []
-            if args_column is not None:
-                if len(args_column) != n:
+            if (
+                sk.cold_armed
+                and args_column is not None
+                and self.param_index.sketch_idx_by_resource
+            ):
+                # VALUE-grade cold ceiling over the group's args column: a
+                # fully-blocked group refuses dense (never enqueued); a
+                # PARTIALLY blocked group needs per-row verdicts, which is
+                # per-entry routing — decline like the other bulk-refusing
+                # rule classes so the columnar spine's ValueError fallback
+                # re-routes through submit_entry on the same flush.
+                vmask = sk.cold_value_mask(
+                    resource, self.param_index, args_column, n
+                )
+                if vmask is not None:
+                    if bool(vmask.all()):
+                        # Row-weighted, matching cold_blocked's bulk count.
+                        sk.note_cold_value_rows(n)
+                        return self._refused_bulk(
+                            resource, n, context_name, origin, acquire,
+                            reason=E.BLOCK_SKETCH, provenance="sketch_cold",
+                            count_shed=False,
+                        )
                     raise ValueError(
-                        f"submit_bulk: args_column length {len(args_column)}"
-                        f" != n={n}"
+                        "submit_bulk: sketch cold-value ceiling needs "
+                        "per-entry verdicts on this group — use submit_many"
                     )
-                if self.param_index.has_rules():
-                    p_cols = self._bulk_param_cols(
-                        self.param_index, resource, args_column
+            with self._lock:
+                findex = self.flow_index
+                dindex = self.degrade_index
+                rows = self.resolve_entry_rows(resource, context_name, origin, entry_type)
+                if rows is None:
+                    if self.sketch.armed:
+                        acq = self._bulk_col(acquire, n, 1)
+                        self.sketch.note_unrouted(resource, int(acq.sum()))
+                    return None
+                slots = findex.resolve_slots(resource, context_name, origin, self.nodes)
+                if findex.cluster_gids and any(
+                    gid in findex.cluster_gids for gid, _ in slots
+                ):
+                    raise ValueError(
+                        "submit_bulk: resource has cluster-mode flow rules "
+                        "(token verdicts are per entry) — use submit_many, "
+                        "which resolves them with one batched token RPC"
                     )
-            now = self.clock.now_ms()
-            op = BulkOp(
-                resource=resource,
-                n=n,
-                ts=self._bulk_col(ts, n, now),
-                acquire=self._bulk_col(acquire, n, 1),
-                rows=rows,
-                slots=slots,
-                d_gids=dindex.gids_for(resource),
-                auth_ok=auth_ok,
-                context_name=context_name,
-                origin=origin,
-                src=(findex, dindex, self.param_index),
-                args_column=args_column,
-                p_cols=p_cols,
-            )
-        # One group-level trace tag, stamped outside the lock (see
-        # submit_entry) while the group is still thread-private.
-        if self.admission_trace.enabled:
-            op.trace = self.admission_trace.make_tag()
-        spec = self.speculative
-        speculated = False
-        if spec.enabled:
-            # Immediate speculative array verdicts BEFORE the append:
-            # the group still rides the flush below for settlement +
-            # reconcile, and it must not be visible to a concurrent
-            # flush until the speculative arrays are installed (a fill
-            # settling it first would be silently overwritten — an
-            # unreconciled mismatch).
-            speculated = spec.try_admit_bulk(op, self.clock.now_ms())
-        with self._lock:
-            self._bulk_entries.append(op)
-            self._bulk_pending_n += n
-            over = len(self._entries) + self._bulk_pending_n >= self.max_batch
+                auth_ok = True
+                arule = self.authority_rules.get(resource)
+                if arule is not None:
+                    from sentinel_tpu.rules.authority_manager import AuthorityRuleManager
+
+                    auth_ok = AuthorityRuleManager.passes(arule, origin)
+                p_cols: List[_BulkParamCols] = []
+                if args_column is not None:
+                    if len(args_column) != n:
+                        raise ValueError(
+                            f"submit_bulk: args_column length {len(args_column)}"
+                            f" != n={n}"
+                        )
+                    if self.param_index.has_rules():
+                        with self.telemetry.phase(
+                            "sentinel.submit.param", flush=seq, n=n
+                        ):
+                            p_cols = self._bulk_param_cols(
+                                self.param_index, resource, args_column
+                            )
+                now = self.clock.now_ms()
+                op = BulkOp(
+                    resource=resource,
+                    n=n,
+                    ts=self._bulk_col(ts, n, now),
+                    acquire=self._bulk_col(acquire, n, 1),
+                    rows=rows,
+                    slots=slots,
+                    d_gids=dindex.gids_for(resource),
+                    auth_ok=auth_ok,
+                    context_name=context_name,
+                    origin=origin,
+                    src=(findex, dindex, self.param_index),
+                    args_column=args_column,
+                    p_cols=p_cols,
+                )
+            # One group-level trace tag, stamped outside the lock (see
+            # submit_entry) while the group is still thread-private.
+            if self.admission_trace.enabled:
+                op.trace = self.admission_trace.make_tag()
+            spec = self.speculative
+            speculated = False
+            if spec.enabled:
+                # Immediate speculative array verdicts BEFORE the append:
+                # the group still rides the flush below for settlement +
+                # reconcile, and it must not be visible to a concurrent
+                # flush until the speculative arrays are installed (a fill
+                # settling it first would be silently overwritten — an
+                # unreconciled mismatch).
+                speculated = spec.try_admit_bulk(op, self.clock.now_ms())
+            with self._lock:
+                self._bulk_entries.append(op)
+                self._bulk_pending_n += n
+                over = len(self._entries) + self._bulk_pending_n >= self.max_batch
         if over:
             self.flush()
         elif speculated:
@@ -2151,43 +2150,46 @@ class Engine:
         ratchets down until the fast tier wrongly blocks everything.
         Pass False for groups known to be device-decided.
         """
-        if n < 1:
-            raise ValueError("submit_exit_bulk: n must be >= 1")
-        if n > self.max_batch:
-            raise ValueError(
-                f"submit_exit_bulk: n={n} exceeds max_batch={self.max_batch}; split the group"
-            )
-        with self._lock:
-            dindex = self.degrade_index
-            now = self.clock.now_ms()
-            rt_col = self._bulk_col(rt, n, 0)
-            np.minimum(rt_col, config.statistic_max_rt, out=rt_col)
-            op = _BulkExitOp(
-                rows=rows,
-                n=n,
-                ts=self._bulk_col(ts, n, now),
-                count=self._bulk_col(count, n, 1),
-                rt=rt_col,
-                err=self._bulk_col(err, n, 0),
-                thr=-1,
-                d_gids=dindex.gids_for(resource) if resource is not None else [],
-                resource=resource,
-                src_dindex=dindex if resource is not None else None,
-            )
-            self._bulk_exits.append(op)
-            self._bulk_exit_pending_n += n
-            over = len(self._exits) + self._bulk_exit_pending_n >= self.max_batch
-        spec = self.speculative
-        if spec.enabled:
-            # Bulk analog of submit_exit's synchronous mirror release
-            # (the counter clamps at zero for device-decided groups
-            # whose admits were never mirror-charged).
-            if resource is not None and speculative is not False:
+        with self.telemetry.phase(
+            "sentinel.submit", flush=self._flush_seq + 1, n=n
+        ):
+            if n < 1:
+                raise ValueError("submit_exit_bulk: n must be >= 1")
+            if n > self.max_batch:
+                raise ValueError(
+                    f"submit_exit_bulk: n={n} exceeds max_batch={self.max_batch}; split the group"
+                )
+            with self._lock:
+                dindex = self.degrade_index
+                now = self.clock.now_ms()
+                rt_col = self._bulk_col(rt, n, 0)
+                np.minimum(rt_col, config.statistic_max_rt, out=rt_col)
+                op = _BulkExitOp(
+                    rows=rows,
+                    n=n,
+                    ts=self._bulk_col(ts, n, now),
+                    count=self._bulk_col(count, n, 1),
+                    rt=rt_col,
+                    err=self._bulk_col(err, n, 0),
+                    thr=-1,
+                    d_gids=dindex.gids_for(resource) if resource is not None else [],
+                    resource=resource,
+                    src_dindex=dindex if resource is not None else None,
+                )
+                self._bulk_exits.append(op)
+                self._bulk_exit_pending_n += n
+                over = len(self._exits) + self._bulk_exit_pending_n >= self.max_batch
+            spec = self.speculative
+            if spec.enabled and resource is not None and speculative is not False:
+                # Bulk analog of submit_exit's synchronous mirror release
+                # (the counter clamps at zero for device-decided groups
+                # whose admits were never mirror-charged).
                 spec.on_exit(
                     resource, n, rows=rows, rt=int(op.rt.sum()),
                     count=int(op.count.sum()), now_ms=now,
                     min_rt=int(op.rt.min()),
                 )
+        if spec.enabled:
             self._spec_maybe_settle()
         if over:
             self.flush()
@@ -2350,9 +2352,10 @@ class Engine:
         pindex: ParamIndex,
         bulk: Sequence[BulkOp] = (),
         staging: Optional[List[Tuple[tuple, tuple]]] = None,
-    ) -> Tuple[Optional[ParamBatch], int]:
+    ) -> Tuple[Optional[ParamBatch], int, int]:
         """Encode hot-param slots plus the host-known rounds bound (max
-        items per value row, pow2-bucketed; 0 → scan fallback). Bulk
+        items per value row, pow2-bucketed; 0 → scan fallback) and the
+        item count. Bulk
         groups' p_cols ride the same item stream as numpy slice
         assignments (no per-request Python), indexed into the flat row
         space after the singles (the same offsets the main encode gives
@@ -2374,7 +2377,7 @@ class Engine:
         exit_rows = [r for op in exits for r in op.p_rows]
         resets = pindex.take_resets()
         if not items and not n_bulk_items and not exit_rows and not resets:
-            return None, 1
+            return None, 1, 0
         n_items = len(items) + n_bulk_items
         s = _pad_pow2(max(1, n_items), 8)
         sx = _pad_pow2(max(1, len(exit_rows)), 8)
@@ -2496,7 +2499,7 @@ class Engine:
         # the ParamBatch may alias these buffers zero-copy.
         if self._arena is not None and staging is not None:
             staging.append((pkey, pbufs))
-        return pb, rounds
+        return pb, rounds, n_items
 
     @staticmethod
     def _param_rounds_for(prow, grade, behavior, ts, acquire) -> int:
@@ -2659,28 +2662,27 @@ class Engine:
 
     @property
     def last_flush_host_ms(self) -> Dict[str, float]:
-        """Host-side breakdown of the most recent flush:
-        ``encode_ms`` (staging-array build, incl. shaping/param
-        encode), ``dispatch_ms`` (the kernel dispatch alone — the
+        """Host-side breakdown of the most recent non-empty flush, a
+        read-only view over its chunks' flight-recorder spans
+        (``FlushSpan``), summed: ``encode_ms`` (staging, shaping and
+        param encode), ``dispatch_ms`` (the kernel dispatch alone — the
         host-blocking cost of a pipelined flush), ``kernel_ms``
-        (dispatch + device→host fetch; a deferred flush counts
-        dispatch only until its fetch materializes) and ``drain_ms``
-        (coalesced in-flight fetches that landed while this breakdown
-        was current — they may belong to earlier dispatches).
-        Diagnostics for bench attribution — a snapshot copy, safe to
-        hold across later flushes."""
-        with self._timing_lock:
-            return dict(self._flush_timing)
+        (dispatch + the chunks' own device→host fetches; a deferred
+        flush counts dispatch only until its fetch materializes),
+        ``fill_ms`` (verdict fill from the fetched arrays) and
+        ``drain_ms`` (coalesced in-flight fetches that landed since the
+        flush began — they may belong to earlier dispatches). With
+        ``sentinel.tpu.telemetry.enabled`` off no span is recorded and
+        every key reads 0. A snapshot copy, safe to hold across later
+        flushes."""
+        return self.telemetry.last_flush_ms()
 
     def _note_drain_ms(self, ms: float) -> None:
-        """Accumulate deferred-fetch time into the current breakdown.
-        Runs from drain/materialize threads outside the flush lock; a
-        drain landing just after a new flush swapped the dict counts
-        toward the new breakdown — benign for diagnostics."""
-        with self._timing_lock:
-            self._flush_timing["drain_ms"] = (
-                self._flush_timing.get("drain_ms", 0.0) + ms
-            )
+        """Record deferred-fetch time: the drain histogram and the
+        newest flush's ``drain_ms``. Runs from drain/materialize
+        threads outside the flush lock; a drain landing just after a
+        new flush swapped its buffers counts toward the new breakdown —
+        benign for diagnostics."""
         if self.telemetry.enabled:
             self.telemetry.note_drain(ms)
         if self.ingest.armed:
@@ -2782,7 +2784,7 @@ class Engine:
         keys on."""
         return self._flush_seq
 
-    def _fetch_refs(self, refs, seqs: Sequence[int]):
+    def _fetch_refs(self, refs, seqs: Sequence[int], span=None):
         """The ONE chokepoint for device→host result fetches: the
         deterministic fault injector fires here (keyed by flush seq),
         and with failover armed the blocking ``jax.device_get`` runs on
@@ -2790,19 +2792,25 @@ class Engine:
         ``sentinel.tpu.failover.fetch.timeout.ms`` — a wedged fetch
         raises :class:`~sentinel_tpu.runtime.failover.DeviceFetchTimeout`
         instead of stranding the caller (and everyone behind the flush
-        lock) forever."""
+        lock) forever. The ``sentinel.fetch`` phase (the host waiting
+        for the kernel, then the copy) is opened on the calling thread,
+        around the watchdog wait, and lands on ``span.fetch_ms``."""
         faults = self.faults
         fo = self.failover
-        if fo.armed:
-            def _run():
-                if faults is not None:
-                    faults.on_fetch(seqs)
-                return jax.device_get(refs)
+        with self.telemetry.phase(
+            "sentinel.fetch", span, "fetch_ms",
+            flush=seqs[0] if seqs else -1, chunks=len(seqs),
+        ):
+            if fo.armed:
+                def _run():
+                    if faults is not None:
+                        faults.on_fetch(seqs)
+                    return jax.device_get(refs)
 
-            return fo.watched(_run, "device fetch", seqs)
-        if faults is not None:
-            faults.on_fetch(seqs)
-        return jax.device_get(refs)
+                return fo.watched(_run, "device fetch", seqs)
+            if faults is not None:
+                faults.on_fetch(seqs)
+            return jax.device_get(refs)
 
     def _quarantine_pending(self) -> None:
         """Quarantine the whole in-flight queue (failover trip): every
@@ -2881,7 +2889,16 @@ class Engine:
         drained by another thread's flush returns with the verdicts
         already filled (the other flush cannot release the lock before
         filling them).
+
+        The call is the ``sentinel.flush`` profiler step, numbered by
+        the flush seq its first chunk is expected to dispatch under
+        (another thread's concurrent dispatch can take that seq first).
         """
+        seq = self._flush_seq + 1
+        with self.telemetry.flush_step(seq):
+            return self._flush(seq)
+
+    def _flush(self, seq: int) -> List[_EntryOp]:
         w = self.ingest_window
         if w.armed and w._exit_buf:
             # Window-batched completions waiting for their columnar
@@ -2906,7 +2923,9 @@ class Engine:
         # Earlier deferred dispatches materialize first (FIFO), so
         # "after flush() every previously submitted op has a verdict"
         # keeps holding in pipelined use.
-        self.drain()
+        tele = self.telemetry
+        with tele.phase("sentinel.flush.drain", flush=seq):
+            self.drain()
         if fo.armed and not fo.healthy:
             # The drain tripped failover: serve the new ops from policy.
             return self._flush_degraded()
@@ -2915,7 +2934,8 @@ class Engine:
             with self._flush_lock:
                 self._flush_locked(drained)
         finally:
-            self._post_flush(drained)
+            with tele.phase("sentinel.post_flush", flush=seq):
+                self._post_flush(drained)
         return drained[0]
 
     def _flush_pipelined(self, depth: int) -> List[_EntryOp]:
@@ -2967,7 +2987,10 @@ class Engine:
             except BaseException:
                 pass
             raise
-        self._drain_pending(keep=keep_dispatched if dispatched else keep_empty)
+        with self.telemetry.phase("sentinel.flush.drain", flush=self._flush_seq):
+            self._drain_pending(
+                keep=keep_dispatched if dispatched else keep_empty
+            )
         if dispatched:
             self._sample_occupancy()
         return drained[0]
@@ -3158,7 +3181,9 @@ class Engine:
                         self._pending_fetches.append(res)
             else:
                 out[1].extend(res)
-        with self._lock:
+        tele = self.telemetry
+        swap = tele.phase("sentinel.flush.swap", flush=self._flush_seq + 1)
+        with swap, self._lock:
             self._maybe_rebase()
             entries, self._entries = self._entries, []
             exits, self._exits = self._exits, []
@@ -3171,12 +3196,6 @@ class Engine:
                 # flush-on-size inside submit followed by an explicit
                 # no-op flush() must not zero the numbers just taken.
                 return 0
-            # Fresh host-side breakdown for this flush (chunks accumulate).
-            with self._timing_lock:
-                self._flush_timing = {
-                    "encode_ms": 0.0, "dispatch_ms": 0.0,
-                    "kernel_ms": 0.0, "drain_ms": 0.0,
-                }
             self._ensure_capacity()
             findex = self.flow_index
             dindex = self.degrade_index
@@ -3268,6 +3287,8 @@ class Engine:
                 if gx.resource is not None and gx.src_dindex is not None and gx.src_dindex is not dindex:
                     gx.d_gids = dindex.gids_for(gx.resource)
                     gx.src_dindex = dindex
+        if tele.enabled:
+            tele.begin_flush(swap.ms)
         # One kernel launch per max_batch slice: bounds device memory
         # for the padded batch regardless of how much queued up.
         mb = max(self.max_batch, 1)
@@ -3393,242 +3414,106 @@ class Engine:
                     op.custom_checked = True
             for g in bulk:
                 SlotChainRegistry.check_bulk_entry(g)
-        # Flight recorder: one span per dispatched chunk. Disabled →
-        # tele is None and the whole block below is a handful of
-        # untaken branches.
+        # Flight recorder: one span per dispatched chunk, opened at
+        # encode start so every phase below lands on it. Disabled →
+        # tele and span are None and the phases only mark the profiler.
         tele = self.telemetry if self.telemetry.enabled else None
         if tele is not None and self._arena is not None:
             arena_h0, arena_m0 = self._arena.hits, self._arena.misses
         else:
             arena_h0 = arena_m0 = 0
-        # Pow2 padding is shard-divisible on any power-of-two mesh once
-        # raised to at least n_shards (enable_mesh enforces pow2).
-        t_enc0 = time.perf_counter()
         n_bulk = sum(g.n for g in bulk)
         m_bulk = sum(g.n for g in bulk_exits)
-        n = max(_pad_pow2(len(entries) + n_bulk, 8), self._n_shards)
-        m = max(_pad_pow2(len(exits) + m_bulk, 8), self._n_shards)
-        k = _pad_pow2(
-            max(
-                1,
-                max((len(op.slots) for op in entries), default=1),
-                max((len(g.slots) for g in bulk), default=1),
-            ),
-            1,
-        )
-        kd = _pad_pow2(
-            max(
-                1,
-                max((len(op.d_gids) for op in entries), default=1),
-                max((len(op.d_gids) for op in exits), default=1),
-                max((len(g.d_gids) for g in bulk), default=1),
-                max((len(g.d_gids) for g in bulk_exits), default=1),
-            ),
-            1,
-        )
-
-        # Entry staging buffers ride the arena (reused across flushes
-        # for repeated (n, k, kd) shapes — the steady state); pooled
-        # buffers hold the previous chunk's data, so every field is
-        # reset to its encode default here, exactly what the fresh
-        # np.zeros/np.full builds used to produce.
-        ekey = ("e", n, k, kd)
-
-        def _build_e():
-            return (
-                np.empty(n, dtype=bool), np.empty(n, dtype=np.int32),
-                np.empty(n, dtype=np.int32), np.empty((n, 4), dtype=np.int32),
-                np.empty((n, k), dtype=np.int32), np.empty((n, k), dtype=np.int32),
-                np.empty(n, dtype=bool), np.empty(n, dtype=bool),
-                np.empty(n, dtype=bool), np.empty((n, kd), dtype=np.int32),
-            )
-
-        ebufs = self._arena.take(ekey, _build_e) if self._arena else _build_e()
-        (e_valid, e_ts, e_acquire, e_rows, e_gid, e_crow, e_prio, e_auth,
-         e_cluster, e_dgid) = ebufs
-        e_valid.fill(False)
-        e_ts.fill(0)
-        e_acquire.fill(1)
-        e_rows.fill(-1)
-        e_gid.fill(-1)
-        e_crow.fill(-1)
-        e_prio.fill(False)
-        e_auth.fill(True)
-        e_cluster.fill(True)
-        e_dgid.fill(-1)
-        ne = len(entries)
-        if ne:
-            # Flat fields fill via one C-level assignment per column
-            # (a per-op per-field Python loop costs ~3× more); only the
-            # ragged slot/dgid columns keep the nested loop.
-            e_valid[:ne] = True
-            e_ts[:ne] = [op.ts for op in entries]
-            e_acquire[:ne] = [op.acquire for op in entries]
-            e_rows[:ne] = [op.rows for op in entries]
-            e_prio[:ne] = [op.prio for op in entries]
-            e_auth[:ne] = [
-                op.auth_ok and op.custom_veto is None for op in entries
-            ]
-            e_cluster[:ne] = [op.cluster_blocked_rule is None for op in entries]
-            for i, op in enumerate(entries):
-                for j, (gid, crow) in enumerate(op.slots[:k]):
-                    e_gid[i, j] = gid
-                    e_crow[i, j] = crow
-                for j, dg in enumerate(op.d_gids[:kd]):
-                    e_dgid[i, j] = dg
-        off_b = len(entries)
-        for g in bulk:
-            sl = slice(off_b, off_b + g.n)
-            e_valid[sl] = True
-            e_ts[sl] = g.ts
-            e_acquire[sl] = g.acquire
-            e_rows[sl] = g.rows
-            for j, (gid, crow) in enumerate(g.slots[:k]):
-                e_gid[sl, j] = gid
-                e_crow[sl, j] = crow
-            for j, dg in enumerate(g.d_gids[:kd]):
-                e_dgid[sl, j] = dg
-            if g.custom_veto_mask is not None:
-                e_auth[sl] = g.auth_ok & ~g.custom_veto_mask
-            else:
-                e_auth[sl] = g.auth_ok
-            off_b += g.n
-
-        xkey = ("x", m, kd)
-
-        def _build_x():
-            return (
-                np.empty(m, dtype=bool), np.empty(m, dtype=np.int32),
-                np.empty(m, dtype=np.int32), np.empty((m, 4), dtype=np.int32),
-                np.empty(m, dtype=np.int32), np.empty(m, dtype=np.int32),
-                np.empty(m, dtype=np.int32), np.empty((m, kd), dtype=np.int32),
-            )
-
-        xbufs = self._arena.take(xkey, _build_x) if self._arena else _build_x()
-        x_valid, x_ts, x_count, x_rows, x_rt, x_err, x_thr, x_dgid = xbufs
-        x_valid.fill(False)
-        x_ts.fill(0)
-        x_count.fill(0)
-        x_rows.fill(-1)
-        x_rt.fill(0)
-        x_err.fill(0)
-        x_thr.fill(0)
-        x_dgid.fill(-1)
-        nx = len(exits)
-        if nx:
-            x_valid[:nx] = True
-            x_ts[:nx] = [op.ts for op in exits]
-            x_count[:nx] = [op.count for op in exits]
-            x_rows[:nx] = [op.rows for op in exits]
-            x_rt[:nx] = [op.rt for op in exits]
-            x_err[:nx] = [op.err for op in exits]
-            x_thr[:nx] = [op.thr for op in exits]
-            for i, op in enumerate(exits):
-                for j, dg in enumerate(op.d_gids[:kd]):
-                    x_dgid[i, j] = dg
-        off_x = len(exits)
-        for g in bulk_exits:
-            sl = slice(off_x, off_x + g.n)
-            x_valid[sl] = True
-            x_ts[sl] = g.ts
-            x_count[sl] = g.count
-            x_rows[sl] = g.rows
-            x_rt[sl] = g.rt
-            x_err[sl] = g.err
-            x_thr[sl] = g.thr
-            for j, dg in enumerate(g.d_gids[:kd]):
-                x_dgid[sl, j] = dg
-            off_x += g.n
-
-        now_host = self.clock.now_ms()
-        batch = FlushBatch(
-            now=jnp.int32(now_host),
-            e_valid=jnp.asarray(e_valid),
-            e_ts=jnp.asarray(e_ts),
-            e_acquire=jnp.asarray(e_acquire),
-            e_rows=jnp.asarray(e_rows),
-            e_rule_gid=jnp.asarray(e_gid),
-            e_check_row=jnp.asarray(e_crow),
-            e_prio=jnp.asarray(e_prio),
-            e_auth_ok=jnp.asarray(e_auth),
-            e_cluster_ok=jnp.asarray(e_cluster),
-            e_dgid=jnp.asarray(e_dgid),
-            x_valid=jnp.asarray(x_valid),
-            x_ts=jnp.asarray(x_ts),
-            x_count=jnp.asarray(x_count),
-            x_rows=jnp.asarray(x_rows),
-            x_rt=jnp.asarray(x_rt),
-            x_err=jnp.asarray(x_err),
-            x_thr=jnp.asarray(x_thr),
-            x_dgid=jnp.asarray(x_dgid),
-        )
-        # Staging buffers go back to the arena only after this chunk's
-        # results are fetched — jnp.asarray may have zero-copied them
-        # into the dispatched computation (see _EncodeArena).
-        staging: List[Tuple[tuple, tuple]] = []
-        if self._arena is not None:
-            staging.append((ekey, ebufs))
-            staging.append((xkey, xbufs))
-
-        sysdev = self._system_device()
-        shaping, sh_rounds = self._encode_shaping(entries, bulk, k, findex)
-        param, p_rounds = self._encode_param(entries, exits, pindex, bulk, staging)
-        # Param-path cost attribution: consume the pick _encode_param
-        # made for THIS chunk immediately (flushes serialize on the
-        # flush lock) — consuming here, before any fault-path early
-        # return, means a pick can never leak onto a later chunk's
-        # span. It lands on the span below once telemetry creates it.
-        at = self.autotune
-        param_pick = at.take_pending_pick() if at.enabled else None
-        # Statistics sketch tier (runtime/sketch.py): aggregate this
-        # chunk's key-id stream and schedule the once-per-window decay
-        # — the fold itself runs inside the kernel, chained on the
-        # donated SketchState exactly like the stats windows.
-        tier = self.sketch
-        sk_batch = None
-        sk_decay = False
-        if tier.armed and self.mesh is None:
-            sk_ids, sk_w = tier.encode_chunk(entries, bulk, findex, pindex)
-            sk_decay = tier.decay_due(now_host)
-            sk_batch = SketchBatch(
-                ids=jnp.asarray(sk_ids), w=jnp.asarray(sk_w)
-            )
-        occ_ms = config.occupy_timeout_ms
-        common = (
-            self.stats,
-            findex.device,
-            self.flow_dyn,
-            dindex.device,
-            self.degrade_dyn,
-            self.param_dyn,
-            sysdev,
-            batch,
-        )
-        # Host-known stage specializations (exact — each skipped stage's
-        # masks would be all-pass): plain DEFAULT-flow traffic compiles
-        # to a much leaner kernel than the fully-general one.
-        flags = dict(
-            with_occupy=any(op.prio for op in entries),
-            with_system=self.system_config is not None,
-            with_degrade=bool(dindex.rules),
-            with_exits=bool(exits) or bool(bulk_exits),
-            shaping_rounds=sh_rounds,
-            param_rounds=p_rounds,
-            # Device-side blocked-resource top-K fold (0 when telemetry
-            # is off — the fold then compiles away entirely).
-            blk_topk=self._blk_topk_k,
-            sketch_decay=sk_decay,
-            # Keys the jit cache on the live window geometry so a
-            # retune_second_window with unchanged shapes (interval-only
-            # change) cannot hit a stale-constant entry.
-            win_key=_ncfg.SECOND_CFG,
-        )
-        t_disp0 = time.perf_counter()
-        with self._timing_lock:
-            self._flush_timing["encode_ms"] += (t_disp0 - t_enc0) * 1e3
         # One flush sequence number per dispatched chunk — the fault
-        # injector's key and the checkpoint cadence counter.
+        # injector's key, the checkpoint cadence counter and the
+        # ``flush`` arg of this chunk's phase spans.
         seq = self._next_flush_seq()
+        span = None
+        if tele is not None:
+            with self._pending_lock:
+                inflight = len(self._pending_fetches)
+            span = tele.begin_span(
+                t0=time.perf_counter(), depth=self._pipeline_depth,
+                inflight=inflight, n_entries=len(entries),
+                n_exits=len(exits), n_bulk=n_bulk, n_bulk_exits=m_bulk,
+                deferred=defer, now_rel_ms=self.clock.now_ms(),
+            )
+        phase = self.telemetry.phase
+        with phase("sentinel.encode", span, "encode_ms", flush=seq):
+            with phase("sentinel.encode.staging", flush=seq):
+                batch, staging, k, kd, now_host = self._encode_staging(
+                    entries, exits, bulk, bulk_exits, n_bulk, m_bulk
+                )
+                sysdev = self._system_device()
+            with phase("sentinel.encode.shaping", span, "shaping_encode_ms",
+                       flush=seq) as ph:
+                shaping, sh_rounds, sh_items = self._encode_shaping(
+                    entries, bulk, k, findex
+                )
+                sh_padded = 0 if shaping is None else shaping.valid.shape[0]
+                ph.set(items=sh_items, padded=sh_padded, rounds=sh_rounds)
+            with phase("sentinel.encode.param", span, "param_encode_ms",
+                       flush=seq) as ph:
+                param, p_rounds, p_items = self._encode_param(
+                    entries, exits, pindex, bulk, staging
+                )
+                ph.set(items=p_items, rounds=p_rounds)
+            # Param-path cost attribution: consume the pick _encode_param
+            # made for THIS chunk immediately (flushes serialize on the
+            # flush lock) — consuming here, before any fault-path early
+            # return, means a pick can never leak onto a later chunk's
+            # span. It lands on the span after dispatch.
+            at = self.autotune
+            param_pick = at.take_pending_pick() if at.enabled else None
+            # Statistics sketch tier (runtime/sketch.py): aggregate this
+            # chunk's key-id stream and schedule the once-per-window decay
+            # — the fold itself runs inside the kernel, chained on the
+            # donated SketchState exactly like the stats windows.
+            tier = self.sketch
+            sk_batch = None
+            sk_decay = False
+            if tier.armed and self.mesh is None:
+                sk_ids, sk_w = tier.encode_chunk(entries, bulk, findex, pindex)
+                sk_decay = tier.decay_due(now_host)
+                sk_batch = SketchBatch(
+                    ids=jnp.asarray(sk_ids), w=jnp.asarray(sk_w)
+                )
+            occ_ms = config.occupy_timeout_ms
+            common = (
+                self.stats,
+                findex.device,
+                self.flow_dyn,
+                dindex.device,
+                self.degrade_dyn,
+                self.param_dyn,
+                sysdev,
+                batch,
+            )
+            # Host-known stage specializations (exact — each skipped stage's
+            # masks would be all-pass): plain DEFAULT-flow traffic compiles
+            # to a much leaner kernel than the fully-general one.
+            flags = dict(
+                with_occupy=any(op.prio for op in entries),
+                with_system=self.system_config is not None,
+                with_degrade=bool(dindex.rules),
+                with_exits=bool(exits) or bool(bulk_exits),
+                shaping_rounds=sh_rounds,
+                param_rounds=p_rounds,
+                # Device-side blocked-resource top-K fold (0 when telemetry
+                # is off — the fold then compiles away entirely).
+                blk_topk=self._blk_topk_k,
+                sketch_decay=sk_decay,
+                # Keys the jit cache on the live window geometry so a
+                # retune_second_window with unchanged shapes (interval-only
+                # change) cannot hit a stale-constant entry.
+                win_key=_ncfg.SECOND_CFG,
+            )
+        if span is not None:
+            span.shaping_items = sh_items
+            span.shaping_padded = sh_padded
+            span.shaping_rounds = sh_rounds
+            span.param_items = p_items
+            span.groups = len(bulk)
         # Flight recorder: spill the chunk's inputs BEFORE dispatch (a
         # dispatch fault must not lose the traffic that caused it); the
         # verdicts follow from the fill path via the one-shot token.
@@ -3665,12 +3550,15 @@ class Engine:
             return flush_step_full_jit(*common, shaping, param, occupy_timeout_ms=occ_ms, **skw, **flags)
 
         try:
-            if fo.armed:
-                # Watchdog-bounded dispatch: a wedged compile/dispatch
-                # trips failover instead of stranding every submitter.
-                out = fo.watched(_dispatch, "kernel dispatch", (seq,))
-            else:
-                out = _dispatch()
+            # The phase sits on this thread, around the watchdog wait,
+            # so the interval stays on the flushing thread's line.
+            with phase("sentinel.dispatch", span, "dispatch_ms", flush=seq):
+                if fo.armed:
+                    # Watchdog-bounded dispatch: a wedged compile/dispatch
+                    # trips failover instead of stranding every submitter.
+                    out = fo.watched(_dispatch, "kernel dispatch", (seq,))
+                else:
+                    out = _dispatch()
         except BaseException as exc:
             if not fo.armed:
                 raise
@@ -3681,7 +3569,7 @@ class Engine:
             # may still read it zero-copy if it did start).
             fo.trip("dispatch", exc, seq)
             return self._degraded_chunk(fo, entries, exits, bulk,
-                                        bulk_exits, defer,
+                                        bulk_exits, defer, span=span,
                                         run_custom_slots=False,
                                         quarantined=True,
                                         cap_tok=cap_tok)
@@ -3693,23 +3581,8 @@ class Engine:
             # The donated sketch chain advances under the flush lock,
             # exactly like the other dyn states.
             tier.dev_state = new_skstate
-        dispatch_ms = (time.perf_counter() - t_disp0) * 1e3
-        with self._timing_lock:
-            self._flush_timing["dispatch_ms"] += dispatch_ms
-            self._flush_timing["kernel_ms"] += dispatch_ms
 
-        span = None
         if tele is not None:
-            with self._pending_lock:
-                inflight = len(self._pending_fetches)
-            span = tele.begin_span(
-                t0=t_enc0, depth=self._pipeline_depth, inflight=inflight,
-                n_entries=len(entries), n_exits=len(exits),
-                n_bulk=n_bulk, n_bulk_exits=m_bulk,
-                deferred=defer, now_rel_ms=now_host,
-            )
-            span.encode_ms = (t_disp0 - t_enc0) * 1e3
-            span.dispatch_ms = dispatch_ms
             if self._arena is not None:
                 span.arena_hits = self._arena.hits - arena_h0
                 span.arena_misses = self._arena.misses - arena_m0
@@ -3886,27 +3759,24 @@ class Engine:
         t_fetch0 = time.perf_counter()
         faulted = False
         try:
-            try:
-                res = _fill(self._fetch_refs(refs, (seq,)))
-            except BaseException as exc:
-                if not fo.armed:
-                    raise
-                # Fetch fault/timeout on the synchronous path: the
-                # verdicts are lost — quarantine the older in-flight
-                # queue and fill this chunk from policy. Callers never
-                # see the raw device exception.
-                faulted = True
-                fo.trip("fetch", exc, seq)
-                res = self._degraded_chunk(
-                    fo, entries, exits, bulk, bulk_exits, defer,
-                    span=span, run_custom_slots=False, quarantined=True,
-                    cap_tok=cap_tok,
-                )
-        finally:
-            with self._timing_lock:
-                self._flush_timing["kernel_ms"] += (
-                    time.perf_counter() - t_fetch0
-                ) * 1e3
+            got = self._fetch_refs(refs, (seq,), span)
+            with phase("sentinel.fill", span, "fill_ms", flush=seq,
+                       groups=len(bulk)):
+                res = _fill(got)
+        except BaseException as exc:
+            if not fo.armed:
+                raise
+            # Fetch fault/timeout on the synchronous path: the
+            # verdicts are lost — quarantine the older in-flight
+            # queue and fill this chunk from policy. Callers never
+            # see the raw device exception.
+            faulted = True
+            fo.trip("fetch", exc, seq)
+            res = self._degraded_chunk(
+                fo, entries, exits, bulk, bulk_exits, defer,
+                span=span, run_custom_slots=False, quarantined=True,
+                cap_tok=cap_tok,
+            )
         # Results fetched → the computation has consumed its (possibly
         # zero-copy) inputs; staging is reusable. ONLY on success: a
         # failed/interrupted fetch proves nothing about the dispatched
@@ -3922,6 +3792,186 @@ class Engine:
                     (time.perf_counter() - t_fetch0) * 1e3
                 )
         return res
+
+    def _encode_staging(
+        self,
+        entries: List[_EntryOp],
+        exits: List[_ExitOp],
+        bulk: List[BulkOp],
+        bulk_exits: List[_BulkExitOp],
+        n_bulk: int,
+        m_bulk: int,
+    ) -> Tuple[FlushBatch, List[Tuple[tuple, tuple]], int, int, int]:
+        """Entry and exit staging of one chunk: the padded host columns
+        (arena-pooled) and their FlushBatch device transfers. Returns
+        (batch, staging buffers to pool after the fetch, slot width k,
+        degrade width kd, the flush's engine-clock now)."""
+        # Pow2 padding is shard-divisible on any power-of-two mesh once
+        # raised to at least n_shards (enable_mesh enforces pow2).
+        n = max(_pad_pow2(len(entries) + n_bulk, 8), self._n_shards)
+        m = max(_pad_pow2(len(exits) + m_bulk, 8), self._n_shards)
+        k = _pad_pow2(
+            max(
+                1,
+                max((len(op.slots) for op in entries), default=1),
+                max((len(g.slots) for g in bulk), default=1),
+            ),
+            1,
+        )
+        kd = _pad_pow2(
+            max(
+                1,
+                max((len(op.d_gids) for op in entries), default=1),
+                max((len(op.d_gids) for op in exits), default=1),
+                max((len(g.d_gids) for g in bulk), default=1),
+                max((len(g.d_gids) for g in bulk_exits), default=1),
+            ),
+            1,
+        )
+
+        # Entry staging buffers ride the arena (reused across flushes
+        # for repeated (n, k, kd) shapes — the steady state); pooled
+        # buffers hold the previous chunk's data, so every field is
+        # reset to its encode default here, exactly what the fresh
+        # np.zeros/np.full builds used to produce.
+        ekey = ("e", n, k, kd)
+
+        def _build_e():
+            return (
+                np.empty(n, dtype=bool), np.empty(n, dtype=np.int32),
+                np.empty(n, dtype=np.int32), np.empty((n, 4), dtype=np.int32),
+                np.empty((n, k), dtype=np.int32), np.empty((n, k), dtype=np.int32),
+                np.empty(n, dtype=bool), np.empty(n, dtype=bool),
+                np.empty(n, dtype=bool), np.empty((n, kd), dtype=np.int32),
+            )
+
+        ebufs = self._arena.take(ekey, _build_e) if self._arena else _build_e()
+        (e_valid, e_ts, e_acquire, e_rows, e_gid, e_crow, e_prio, e_auth,
+         e_cluster, e_dgid) = ebufs
+        e_valid.fill(False)
+        e_ts.fill(0)
+        e_acquire.fill(1)
+        e_rows.fill(-1)
+        e_gid.fill(-1)
+        e_crow.fill(-1)
+        e_prio.fill(False)
+        e_auth.fill(True)
+        e_cluster.fill(True)
+        e_dgid.fill(-1)
+        ne = len(entries)
+        if ne:
+            # Flat fields fill via one C-level assignment per column
+            # (a per-op per-field Python loop costs ~3× more); only the
+            # ragged slot/dgid columns keep the nested loop.
+            e_valid[:ne] = True
+            e_ts[:ne] = [op.ts for op in entries]
+            e_acquire[:ne] = [op.acquire for op in entries]
+            e_rows[:ne] = [op.rows for op in entries]
+            e_prio[:ne] = [op.prio for op in entries]
+            e_auth[:ne] = [
+                op.auth_ok and op.custom_veto is None for op in entries
+            ]
+            e_cluster[:ne] = [op.cluster_blocked_rule is None for op in entries]
+            for i, op in enumerate(entries):
+                for j, (gid, crow) in enumerate(op.slots[:k]):
+                    e_gid[i, j] = gid
+                    e_crow[i, j] = crow
+                for j, dg in enumerate(op.d_gids[:kd]):
+                    e_dgid[i, j] = dg
+        off_b = len(entries)
+        for g in bulk:
+            sl = slice(off_b, off_b + g.n)
+            e_valid[sl] = True
+            e_ts[sl] = g.ts
+            e_acquire[sl] = g.acquire
+            e_rows[sl] = g.rows
+            for j, (gid, crow) in enumerate(g.slots[:k]):
+                e_gid[sl, j] = gid
+                e_crow[sl, j] = crow
+            for j, dg in enumerate(g.d_gids[:kd]):
+                e_dgid[sl, j] = dg
+            if g.custom_veto_mask is not None:
+                e_auth[sl] = g.auth_ok & ~g.custom_veto_mask
+            else:
+                e_auth[sl] = g.auth_ok
+            off_b += g.n
+
+        xkey = ("x", m, kd)
+
+        def _build_x():
+            return (
+                np.empty(m, dtype=bool), np.empty(m, dtype=np.int32),
+                np.empty(m, dtype=np.int32), np.empty((m, 4), dtype=np.int32),
+                np.empty(m, dtype=np.int32), np.empty(m, dtype=np.int32),
+                np.empty(m, dtype=np.int32), np.empty((m, kd), dtype=np.int32),
+            )
+
+        xbufs = self._arena.take(xkey, _build_x) if self._arena else _build_x()
+        x_valid, x_ts, x_count, x_rows, x_rt, x_err, x_thr, x_dgid = xbufs
+        x_valid.fill(False)
+        x_ts.fill(0)
+        x_count.fill(0)
+        x_rows.fill(-1)
+        x_rt.fill(0)
+        x_err.fill(0)
+        x_thr.fill(0)
+        x_dgid.fill(-1)
+        nx = len(exits)
+        if nx:
+            x_valid[:nx] = True
+            x_ts[:nx] = [op.ts for op in exits]
+            x_count[:nx] = [op.count for op in exits]
+            x_rows[:nx] = [op.rows for op in exits]
+            x_rt[:nx] = [op.rt for op in exits]
+            x_err[:nx] = [op.err for op in exits]
+            x_thr[:nx] = [op.thr for op in exits]
+            for i, op in enumerate(exits):
+                for j, dg in enumerate(op.d_gids[:kd]):
+                    x_dgid[i, j] = dg
+        off_x = len(exits)
+        for g in bulk_exits:
+            sl = slice(off_x, off_x + g.n)
+            x_valid[sl] = True
+            x_ts[sl] = g.ts
+            x_count[sl] = g.count
+            x_rows[sl] = g.rows
+            x_rt[sl] = g.rt
+            x_err[sl] = g.err
+            x_thr[sl] = g.thr
+            for j, dg in enumerate(g.d_gids[:kd]):
+                x_dgid[sl, j] = dg
+            off_x += g.n
+
+        now_host = self.clock.now_ms()
+        batch = FlushBatch(
+            now=jnp.int32(now_host),
+            e_valid=jnp.asarray(e_valid),
+            e_ts=jnp.asarray(e_ts),
+            e_acquire=jnp.asarray(e_acquire),
+            e_rows=jnp.asarray(e_rows),
+            e_rule_gid=jnp.asarray(e_gid),
+            e_check_row=jnp.asarray(e_crow),
+            e_prio=jnp.asarray(e_prio),
+            e_auth_ok=jnp.asarray(e_auth),
+            e_cluster_ok=jnp.asarray(e_cluster),
+            e_dgid=jnp.asarray(e_dgid),
+            x_valid=jnp.asarray(x_valid),
+            x_ts=jnp.asarray(x_ts),
+            x_count=jnp.asarray(x_count),
+            x_rows=jnp.asarray(x_rows),
+            x_rt=jnp.asarray(x_rt),
+            x_err=jnp.asarray(x_err),
+            x_thr=jnp.asarray(x_thr),
+            x_dgid=jnp.asarray(x_dgid),
+        )
+        # Staging buffers go back to the arena only after this chunk's
+        # results are fetched — jnp.asarray may have zero-copied them
+        # into the dispatched computation (see _EncodeArena).
+        staging: List[Tuple[tuple, tuple]] = []
+        if self._arena is not None:
+            staging.append((ekey, ebufs))
+            staging.append((xkey, xbufs))
+        return batch, staging, k, kd, now_host
 
     def _degraded_chunk(
         self, fo, entries, exits, bulk, bulk_exits, defer, span=None,
@@ -4400,17 +4450,17 @@ class Engine:
 
     def _encode_shaping(
         self, entries: List[_EntryOp], bulk: List[BulkOp], k: int, findex: FlowIndex
-    ) -> Tuple[Optional[ShapingBatch], int]:
+    ) -> Tuple[Optional[ShapingBatch], int, int]:
         """Gather (entry, slot) pairs governed by shaping controllers
         into the compact arrays the pacer recurrence consumes, plus the
         host-known rounds bound (max items per rule, pow2-bucketed; 0 →
-        scan fallback). (None, 1) when the batch touches no shaping
-        rules (the fast path). Bulk groups contribute column blocks (an
+        scan fallback) and the item count. (None, 1, 0) when the batch
+        touches no shaping rules (the fast path). Bulk groups contribute column blocks (an
         item per group entry per shaping slot) without per-entry
         Python."""
         sg = findex.shaping_gids
         if not sg:
-            return None, 1
+            return None, 1, 0
         items = []
         for i, op in enumerate(entries):
             for j, (gid, crow) in enumerate(op.slots[:k]):
@@ -4441,7 +4491,7 @@ class Engine:
                     )
             off += g.n
         if not cols:
-            return None, 1
+            return None, 1, 0
         flat_pos, gid, row, eidx, ts, acquire = (
             np.concatenate([c[a] for c in cols]) for a in range(6)
         )
@@ -4461,7 +4511,7 @@ class Engine:
             flat_pos=jnp.asarray(_p(flat_pos)),
             ts=jnp.asarray(_p(ts)),
             acquire=jnp.asarray(_p(acquire, 1)),
-        ), self._shaping_rounds_for(gid, ts, acquire, findex)
+        ), self._shaping_rounds_for(gid, ts, acquire, findex), total
 
     @staticmethod
     def _shaping_rounds_for(gid, ts, acquire, findex: FlowIndex) -> int:

@@ -714,105 +714,108 @@ def flush_entries(
     """
     n = batch.e_valid.shape[0]
 
-    # ---- phase 2a: authority (AuthoritySlot) ----
-    live = batch.e_valid & batch.e_auth_ok
+    with jax.named_scope("sentinel/system"):
+        # ---- phase 2a: authority (AuthoritySlot) ----
+        live = batch.e_valid & batch.e_auth_ok
 
-    # ---- phase 2b: system protection (SystemSlot) ----
-    if with_system:
-        sys_ok, sys_type = system_check(stats, sysdev, batch, live)
-        live = live & sys_ok
-    else:
-        sys_ok = jnp.ones((n,), dtype=bool)
-        sys_type = jnp.full((n,), SYS_NONE, dtype=jnp.int32)
-
-    # ---- phase 2b': hot-parameter rules (ParamFlowSlot, order -3000) ----
-    wait_param = jnp.zeros((n,), dtype=jnp.int32)
-    param_ok = jnp.ones((n,), dtype=bool)
-    if param_pre is not None:
-        param_ok, wait_param = param_pre
-    elif param is not None:
-        # Exits release per-value thread slots before this batch's checks
-        # (ParamFlowStatisticExitCallback runs at completion).
-        pr0 = pdyn.threads.shape[0]
-        dec_rows = jnp.where(param.exit_rows >= 0, param.exit_rows, jnp.int32(pr0))
-        pdyn = pdyn._replace(threads=pdyn.threads.at[dec_rows].add(-1, mode="drop"))
-        param_live = param._replace(valid=param.valid & live[param.eidx])
-        pdyn, p_ok_s, p_wait_s = run_param(pdyn, param_live, rounds=param_rounds)
-        eidx_p = jnp.where(param_live.valid, param.eidx, jnp.int32(n))
-        param_ok = param_ok.at[eidx_p].min(p_ok_s, mode="drop")
-        wait_param = wait_param.at[eidx_p].max(p_wait_s, mode="drop")
-    live = live & param_ok
-
-    # ---- phase 2c: flow rules (FlowSlot / FlowRuleChecker) ----
-    (
-        slot_ok, flow_pass, pass_plus_consumed, occupied, occupy_wait,
-        occ_slot_nk, occ_target_nk,
-    ) = flow_admission(
-        stats, flow_dev, batch, live, occupy_timeout_ms, with_occupy=with_occupy
-    )
-    occupied = occupied & live
-    wait_ms = jnp.maximum(jnp.zeros((n,), dtype=jnp.int32), jnp.where(occupied, occupy_wait, 0))
-    if shaping is not None:
-        # shaping controllers (rate-limiter / warm-up); entries already
-        # blocked upstream must not advance pacer state.
-        k = batch.e_rule_gid.shape[1]
-        ppc_s = pass_plus_consumed[jnp.clip(shaping.flat_pos, 0, n * k - 1)]
-        prev_s = _prev_second_pass(stats, shaping.row, shaping.ts)
-        interval_sec = _ncfg.SECOND_CFG.interval_ms / 1000.0
-        shaping_live = shaping._replace(valid=shaping.valid & live[shaping.eidx])
-        flow_dyn, ok_s, wait_s = run_shaping(
-            flow_dev, flow_dyn, shaping_live, ppc_s, prev_s, interval_sec,
-            rounds=shaping_rounds,
+        # ---- phase 2b: system protection (SystemSlot) ----
+        if with_system:
+            sys_ok, sys_type = system_check(stats, sysdev, batch, live)
+            live = live & sys_ok
+        else:
+            sys_ok = jnp.ones((n,), dtype=bool)
+            sys_type = jnp.full((n,), SYS_NONE, dtype=jnp.int32)
+    with jax.named_scope("sentinel/param"):
+        # ---- phase 2b': hot-parameter rules (ParamFlowSlot, order -3000) ----
+        wait_param = jnp.zeros((n,), dtype=jnp.int32)
+        param_ok = jnp.ones((n,), dtype=bool)
+        if param_pre is not None:
+            param_ok, wait_param = param_pre
+        elif param is not None:
+            # Exits release per-value thread slots before this batch's checks
+            # (ParamFlowStatisticExitCallback runs at completion).
+            pr0 = pdyn.threads.shape[0]
+            dec_rows = jnp.where(param.exit_rows >= 0, param.exit_rows, jnp.int32(pr0))
+            pdyn = pdyn._replace(threads=pdyn.threads.at[dec_rows].add(-1, mode="drop"))
+            param_live = param._replace(valid=param.valid & live[param.eidx])
+            pdyn, p_ok_s, p_wait_s = run_param(pdyn, param_live, rounds=param_rounds)
+            eidx_p = jnp.where(param_live.valid, param.eidx, jnp.int32(n))
+            param_ok = param_ok.at[eidx_p].min(p_ok_s, mode="drop")
+            wait_param = wait_param.at[eidx_p].max(p_wait_s, mode="drop")
+        live = live & param_ok
+    with jax.named_scope("sentinel/flow"):
+        # ---- phase 2c: flow rules (FlowSlot / FlowRuleChecker) ----
+        (
+            slot_ok, flow_pass, pass_plus_consumed, occupied, occupy_wait,
+            occ_slot_nk, occ_target_nk,
+        ) = flow_admission(
+            stats, flow_dev, batch, live, occupy_timeout_ms, with_occupy=with_occupy
         )
-        flat_ok = slot_ok.reshape(-1)
-        scatter_pos = jnp.where(
-            shaping_live.valid, shaping.flat_pos, jnp.int32(flat_ok.shape[0])
-        )
-        # bool .min scatter == logical AND with existing verdicts.
-        flat_ok = flat_ok.at[scatter_pos].min(ok_s, mode="drop")
-        slot_ok = flat_ok.reshape(slot_ok.shape)
-        flow_pass = slot_ok.all(axis=1)
-        eidx_scatter = jnp.where(shaping_live.valid, shaping.eidx, jnp.int32(n))
-        wait_ms = wait_ms.at[eidx_scatter].max(wait_s, mode="drop")
-    if shaping_pre is not None:
-        sp_valid, sp_flat, sp_eidx, sp_ok, sp_wait = shaping_pre
-        flat_ok = slot_ok.reshape(-1)
-        scatter_pos = jnp.where(sp_valid, sp_flat, jnp.int32(flat_ok.shape[0]))
-        flat_ok = flat_ok.at[scatter_pos].min(sp_ok, mode="drop")
-        slot_ok = flat_ok.reshape(slot_ok.shape)
-        flow_pass = slot_ok.all(axis=1)
-        eidx_scatter = jnp.where(sp_valid, sp_eidx, jnp.int32(n))
-        wait_ms = wait_ms.at[eidx_scatter].max(sp_wait, mode="drop")
-    flow_pass = flow_pass & batch.e_cluster_ok
-    live2 = live & flow_pass
-    wait_ms = jnp.where(live2, wait_ms, 0)
-
-    # ---- phase 2d: circuit breakers (DegradeSlot.entry) ----
-    # Occupied entries bypass the breaker: the reference's
-    # PriorityWaitException aborts the slot chain before DegradeSlot
-    # (FlowSlot order −2000 < DegradeSlot −1000), and StatisticSlot
-    # catches it to count only the thread acquire.
-    occ_live = occupied & live2
-    if with_degrade:
-        dslot_ok, probe_slot = breaker_try_pass(
-            ddev, ddyn, batch.e_dgid, batch.e_ts, live2 & ~occupied, probe_allowed
-        )
-        deg_pass = dslot_ok.all(axis=1) | occ_live
-    else:
-        dslot_ok = jnp.ones(batch.e_dgid.shape, dtype=bool)
-        deg_pass = jnp.ones((n,), dtype=bool)
-
-    admitted = live2 & deg_pass
-    if commit:
+        occupied = occupied & live
+        wait_ms = jnp.maximum(jnp.zeros((n,), dtype=jnp.int32), jnp.where(occupied, occupy_wait, 0))
+    with jax.named_scope("sentinel/shaping"):
+        if shaping is not None:
+            # shaping controllers (rate-limiter / warm-up); entries already
+            # blocked upstream must not advance pacer state.
+            k = batch.e_rule_gid.shape[1]
+            ppc_s = pass_plus_consumed[jnp.clip(shaping.flat_pos, 0, n * k - 1)]
+            prev_s = _prev_second_pass(stats, shaping.row, shaping.ts)
+            interval_sec = _ncfg.SECOND_CFG.interval_ms / 1000.0
+            shaping_live = shaping._replace(valid=shaping.valid & live[shaping.eidx])
+            flow_dyn, ok_s, wait_s = run_shaping(
+                flow_dev, flow_dyn, shaping_live, ppc_s, prev_s, interval_sec,
+                rounds=shaping_rounds,
+            )
+            flat_ok = slot_ok.reshape(-1)
+            scatter_pos = jnp.where(
+                shaping_live.valid, shaping.flat_pos, jnp.int32(flat_ok.shape[0])
+            )
+            # bool .min scatter == logical AND with existing verdicts.
+            flat_ok = flat_ok.at[scatter_pos].min(ok_s, mode="drop")
+            slot_ok = flat_ok.reshape(slot_ok.shape)
+            flow_pass = slot_ok.all(axis=1)
+            eidx_scatter = jnp.where(shaping_live.valid, shaping.eidx, jnp.int32(n))
+            wait_ms = wait_ms.at[eidx_scatter].max(wait_s, mode="drop")
+        if shaping_pre is not None:
+            sp_valid, sp_flat, sp_eidx, sp_ok, sp_wait = shaping_pre
+            flat_ok = slot_ok.reshape(-1)
+            scatter_pos = jnp.where(sp_valid, sp_flat, jnp.int32(flat_ok.shape[0]))
+            flat_ok = flat_ok.at[scatter_pos].min(sp_ok, mode="drop")
+            slot_ok = flat_ok.reshape(slot_ok.shape)
+            flow_pass = slot_ok.all(axis=1)
+            eidx_scatter = jnp.where(sp_valid, sp_eidx, jnp.int32(n))
+            wait_ms = wait_ms.at[eidx_scatter].max(sp_wait, mode="drop")
+    with jax.named_scope("sentinel/flow"):
+        flow_pass = flow_pass & batch.e_cluster_ok
+        live2 = live & flow_pass
+        wait_ms = jnp.where(live2, wait_ms, 0)
+    with jax.named_scope("sentinel/degrade"):
+        # ---- phase 2d: circuit breakers (DegradeSlot.entry) ----
+        # Occupied entries bypass the breaker: the reference's
+        # PriorityWaitException aborts the slot chain before DegradeSlot
+        # (FlowSlot order −2000 < DegradeSlot −1000), and StatisticSlot
+        # catches it to count only the thread acquire.
+        occ_live = occupied & live2
         if with_degrade:
+            dslot_ok, probe_slot = breaker_try_pass(
+                ddev, ddyn, batch.e_dgid, batch.e_ts, live2 & ~occupied, probe_allowed
+            )
+            deg_pass = dslot_ok.all(axis=1) | occ_live
+        else:
+            dslot_ok = jnp.ones(batch.e_dgid.shape, dtype=bool)
+            deg_pass = jnp.ones((n,), dtype=bool)
+
+        admitted = live2 & deg_pass
+        if commit and with_degrade:
             ddyn = apply_probe_transitions(
                 ddyn, batch.e_dgid, probe_slot, admitted & ~occupied
             )
-        # Borrows persist only for entries that were finally admitted —
-        # an entry vetoed by another slot never borrowed in the
-        # reference (PriorityWaitException would have aborted the chain
-        # with a pass before that slot could veto).
-        if with_occupy:
+    with jax.named_scope("sentinel/account"):
+        if commit and with_occupy:
+            # Borrows persist only for entries that were finally admitted —
+            # an entry vetoed by another slot never borrowed in the
+            # reference (PriorityWaitException would have aborted the chain
+            # with a pass before that slot could veto).
             stats = commit_borrow_slab(
                 stats,
                 occ_slot_nk & (admitted & occupied)[:, None],
@@ -820,82 +823,81 @@ def flush_entries(
                 batch.e_acquire,
                 batch.e_check_row,
             )
-    wait_ms = jnp.maximum(wait_ms, jnp.where(admitted, wait_param, 0))
+        wait_ms = jnp.maximum(wait_ms, jnp.where(admitted, wait_param, 0))
 
-    # Per-value thread acquire (ParamFlowStatisticEntryCallback.onPass):
-    # +1 per thread-grade param slot of an admitted entry.
-    if param is not None and commit:
-        pr = pdyn.threads.shape[0]
-        inc_slot = (
-            param.valid
-            & (param.grade == C.FLOW_GRADE_THREAD)
-            & admitted[param.eidx]
-        )
-        inc_rows = jnp.where(inc_slot, param.prow, jnp.int32(pr))
-        pdyn = pdyn._replace(threads=pdyn.threads.at[inc_rows].add(1, mode="drop"))
+        # Per-value thread acquire (ParamFlowStatisticEntryCallback.onPass):
+        # +1 per thread-grade param slot of an admitted entry.
+        if param is not None and commit:
+            pr = pdyn.threads.shape[0]
+            inc_slot = (
+                param.valid
+                & (param.grade == C.FLOW_GRADE_THREAD)
+                & admitted[param.eidx]
+            )
+            inc_rows = jnp.where(inc_slot, param.prow, jnp.int32(pr))
+            pdyn = pdyn._replace(threads=pdyn.threads.at[inc_rows].add(1, mode="drop"))
 
-    reason = jnp.full((n,), E.PASS, dtype=jnp.int32)
-    reason = jnp.where(batch.e_valid & ~deg_pass, jnp.int32(E.BLOCK_DEGRADE), reason)
-    reason = jnp.where(batch.e_valid & ~flow_pass, jnp.int32(E.BLOCK_FLOW), reason)
-    reason = jnp.where(batch.e_valid & ~param_ok, jnp.int32(E.BLOCK_PARAM), reason)
-    reason = jnp.where(batch.e_valid & ~sys_ok, jnp.int32(E.BLOCK_SYSTEM), reason)
-    reason = jnp.where(
-        batch.e_valid & ~batch.e_auth_ok, jnp.int32(E.BLOCK_AUTHORITY), reason
-    )
-    reason = jnp.where(admitted, jnp.int32(E.PASS), reason)
+        reason = jnp.full((n,), E.PASS, dtype=jnp.int32)
+        reason = jnp.where(batch.e_valid & ~deg_pass, jnp.int32(E.BLOCK_DEGRADE), reason)
+        reason = jnp.where(batch.e_valid & ~flow_pass, jnp.int32(E.BLOCK_FLOW), reason)
+        reason = jnp.where(batch.e_valid & ~param_ok, jnp.int32(E.BLOCK_PARAM), reason)
+        reason = jnp.where(batch.e_valid & ~sys_ok, jnp.int32(E.BLOCK_SYSTEM), reason)
+        reason = jnp.where(
+            batch.e_valid & ~batch.e_auth_ok, jnp.int32(E.BLOCK_AUTHORITY), reason
+        )
+        reason = jnp.where(admitted, jnp.int32(E.PASS), reason)
 
-    # ---- phase 3: entry accounting (StatisticSlot.entry:64-120) ----
-    if commit:
-        e_rows_f = batch.e_rows.reshape(-1)
-        e_mask = (e_rows_f >= 0) & jnp.repeat(batch.e_valid, 4)
-        adm4 = jnp.repeat(admitted, 4)
-        # Occupied entries: thread acquire + OCCUPIED_PASS now; their
-        # PASS materialises when the borrowed window becomes current
-        # (StatisticSlot's PriorityWaitException branch + the
-        # DefaultController addOccupiedPass call).
-        occ4 = jnp.repeat(occupied & admitted, 4)
-        acq4 = jnp.repeat(batch.e_acquire, 4)
-        e_deltas = _scatter_cols(
-            4 * n,
-            PASS=jnp.where(adm4 & ~occ4, acq4, 0),
-            BLOCK=jnp.where(adm4, 0, acq4),
-        )
-        # Minute window: occupied entries count PASS + OCCUPIED_PASS
-        # immediately (StatisticNode.addOccupiedPass writes both to
-        # rollingCounterInMinute, node/StatisticNode.java:343-346); the
-        # second window's pass arrives via the future slab instead.
-        e_deltas_min = _scatter_cols(
-            4 * n,
-            PASS=jnp.where(adm4, acq4, 0),
-            BLOCK=jnp.where(adm4, 0, acq4),
-            OCCUPIED_PASS=jnp.where(occ4, acq4, 0),
-        )
-        e_thr = jnp.where(adm4, 1, 0).astype(jnp.int32)
-        stats = apply_updates(
-            stats, e_rows_f, jnp.repeat(batch.e_ts, 4), e_deltas, None, e_thr, e_mask,
-            minute_deltas=e_deltas_min,
-        )
-
-    blk_rows = blk_weight = None
-    if blk_topk > 0:
-        # Blocked acquire weight per cluster-node row (e_rows[:, 1] is
-        # the resource's ClusterNode — always >= 0 for valid entries).
-        # Dense scatter-add into [n_rows + 1] with the last slot as the
-        # dump row for non-blocked/padding entries, then one top_k:
-        # O(n_rows) work against an already-O(n_rows)-sized state, and
-        # exact within the batch.
-        r_rows = stats.n_rows
-        blocked_w = jnp.where(
-            batch.e_valid & ~admitted, batch.e_acquire, 0
-        ).astype(jnp.int32)
-        crow = jnp.clip(batch.e_rows[:, 1], 0, r_rows - 1)
-        scat = jnp.where(blocked_w > 0, crow, jnp.int32(r_rows))
-        dense = jnp.zeros((r_rows + 1,), dtype=jnp.int32).at[scat].add(blocked_w)
-        blk_weight, blk_rows = jax.lax.top_k(
-            dense[:r_rows], min(blk_topk, r_rows)
-        )
-        blk_rows = blk_rows.astype(jnp.int32)
-
+        # ---- phase 3: entry accounting (StatisticSlot.entry:64-120) ----
+        if commit:
+            e_rows_f = batch.e_rows.reshape(-1)
+            e_mask = (e_rows_f >= 0) & jnp.repeat(batch.e_valid, 4)
+            adm4 = jnp.repeat(admitted, 4)
+            # Occupied entries: thread acquire + OCCUPIED_PASS now; their
+            # PASS materialises when the borrowed window becomes current
+            # (StatisticSlot's PriorityWaitException branch + the
+            # DefaultController addOccupiedPass call).
+            occ4 = jnp.repeat(occupied & admitted, 4)
+            acq4 = jnp.repeat(batch.e_acquire, 4)
+            e_deltas = _scatter_cols(
+                4 * n,
+                PASS=jnp.where(adm4 & ~occ4, acq4, 0),
+                BLOCK=jnp.where(adm4, 0, acq4),
+            )
+            # Minute window: occupied entries count PASS + OCCUPIED_PASS
+            # immediately (StatisticNode.addOccupiedPass writes both to
+            # rollingCounterInMinute, node/StatisticNode.java:343-346); the
+            # second window's pass arrives via the future slab instead.
+            e_deltas_min = _scatter_cols(
+                4 * n,
+                PASS=jnp.where(adm4, acq4, 0),
+                BLOCK=jnp.where(adm4, 0, acq4),
+                OCCUPIED_PASS=jnp.where(occ4, acq4, 0),
+            )
+            e_thr = jnp.where(adm4, 1, 0).astype(jnp.int32)
+            stats = apply_updates(
+                stats, e_rows_f, jnp.repeat(batch.e_ts, 4), e_deltas, None, e_thr, e_mask,
+                minute_deltas=e_deltas_min,
+            )
+    with jax.named_scope("sentinel/topk"):
+        blk_rows = blk_weight = None
+        if blk_topk > 0:
+            # Blocked acquire weight per cluster-node row (e_rows[:, 1] is
+            # the resource's ClusterNode — always >= 0 for valid entries).
+            # Dense scatter-add into [n_rows + 1] with the last slot as the
+            # dump row for non-blocked/padding entries, then one top_k:
+            # O(n_rows) work against an already-O(n_rows)-sized state, and
+            # exact within the batch.
+            r_rows = stats.n_rows
+            blocked_w = jnp.where(
+                batch.e_valid & ~admitted, batch.e_acquire, 0
+            ).astype(jnp.int32)
+            crow = jnp.clip(batch.e_rows[:, 1], 0, r_rows - 1)
+            scat = jnp.where(blocked_w > 0, crow, jnp.int32(r_rows))
+            dense = jnp.zeros((r_rows + 1,), dtype=jnp.int32).at[scat].add(blocked_w)
+            blk_weight, blk_rows = jax.lax.top_k(
+                dense[:r_rows], min(blk_topk, r_rows)
+            )
+            blk_rows = blk_rows.astype(jnp.int32)
     result = FlushResult(
         admitted=admitted,
         reason=reason,
@@ -964,10 +966,17 @@ def flush_step(
     """
     from sentinel_tpu.metrics.nodes import materialize_matured
 
-    stats = materialize_matured(stats, batch.now)
-    stats, ddyn = apply_exit_phase(
-        stats, ddev, ddyn, batch, with_exits=with_exits, with_degrade=with_degrade
-    )
+    # Every op sits under one ``sentinel/<stage>`` scope (roll, exits,
+    # system, param, flow, shaping, degrade, account, topk, sketch): the
+    # op metadata a device trace attributes its time by. Scopes change
+    # no computation, only the metadata and so the compile-cache key.
+    with jax.named_scope("sentinel/roll"):
+        stats = materialize_matured(stats, batch.now)
+    with jax.named_scope("sentinel/exits"):
+        stats, ddyn = apply_exit_phase(
+            stats, ddev, ddyn, batch, with_exits=with_exits,
+            with_degrade=with_degrade,
+        )
     stats, flow_dyn, ddyn, pdyn, result = flush_entries(
         stats, flow_dev, flow_dyn, ddev, ddyn, pdyn, sysdev, batch, shaping, param,
         occupy_timeout_ms=occupy_timeout_ms,
@@ -976,7 +985,8 @@ def flush_step(
         blk_topk=blk_topk,
     )
     if skstate is not None and sk is not None:
-        skstate = sketch_fold(skstate, sk, decay=sketch_decay)
+        with jax.named_scope("sentinel/sketch"):
+            skstate = sketch_fold(skstate, sk, decay=sketch_decay)
     return stats, flow_dyn, ddyn, pdyn, skstate, result
 
 

@@ -207,9 +207,10 @@ def engine_telemetry_lines(engine, openmetrics: bool = False) -> List[str]:
     )
 
     # Per-stage host breakdown of the most recent flush
-    # (Engine.last_flush_host_ms, wired off the bench-only path).
+    # (Engine.last_flush_host_ms, a view over its flight-recorder spans).
     lf = engine.last_flush_host_ms
-    for stage in ("encode_ms", "dispatch_ms", "kernel_ms", "drain_ms"):
+    for stage in ("encode_ms", "dispatch_ms", "kernel_ms", "fill_ms",
+                  "drain_ms"):
         out += _gauge(
             f"{p}_last_flush_{stage}",
             f"Most recent flush host breakdown: {stage}",

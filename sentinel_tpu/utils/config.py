@@ -81,22 +81,18 @@ class SentinelConfig:
     # Engine flight recorder (metrics/telemetry.py): per-flush spans,
     # latency histograms and the blocked-resource sketch. Enabled by
     # default — the off position compiles the kernel sketch fold away
-    # and skips every span record (the ≤2% overhead contract is
+    # and skips every span record, so Engine.last_flush_host_ms (read
+    # from the spans) reads zeros (the ≤2% overhead contract is
     # enforced by the telemetry bench test).
     TELEMETRY_ENABLED = "sentinel.tpu.telemetry.enabled"
     TELEMETRY_RING = "sentinel.tpu.telemetry.ring"
     # Device-side top-K blocked-resource candidates folded into each
-    # flush's kernel outputs (0 disables the fold entirely). The
-    # ``blocked.topk`` spelling is preferred since the statistics
-    # sketch tier (sentinel.tpu.sketch.*) landed; the historical
-    # ``telemetry.sketch.*`` keys stay as accepted fallbacks (read when
-    # the new key is unset) so existing property files keep working.
+    # flush's kernel outputs (0 disables the fold entirely) — named
+    # apart from the statistics sketch tier (sentinel.tpu.sketch.*).
     TELEMETRY_BLOCKED_TOPK_K = "sentinel.tpu.telemetry.blocked.topk.k"
-    TELEMETRY_SKETCH_K = "sentinel.tpu.telemetry.sketch.k"
     # Host-side space-saving summary capacity the per-flush top-Ks
-    # merge into (same preferred/fallback pairing as above).
+    # merge into.
     TELEMETRY_BLOCKED_TOPK_CAP = "sentinel.tpu.telemetry.blocked.topk.capacity"
-    TELEMETRY_SKETCH_CAP = "sentinel.tpu.telemetry.sketch.capacity"
     # How many blocked-top-K rows the exports list (Prometheus
     # sentinel_engine_blocked_weight, the `telemetry` command, the
     # sketch tier's candidate listing) when the device fold is off —
@@ -521,12 +517,8 @@ class SentinelConfig:
         ARENA_PER_KEY: "4",
         TELEMETRY_ENABLED: "true",
         TELEMETRY_RING: "4096",
-        TELEMETRY_SKETCH_K: "8",
-        TELEMETRY_SKETCH_CAP: "64",
-        # -1 = unset: fall back to the historical telemetry.sketch.*
-        # spelling above.
-        TELEMETRY_BLOCKED_TOPK_K: "-1",
-        TELEMETRY_BLOCKED_TOPK_CAP: "-1",
+        TELEMETRY_BLOCKED_TOPK_K: "8",
+        TELEMETRY_BLOCKED_TOPK_CAP: "64",
         TELEMETRY_TOPK_EXPORT: "10",
         SKETCH_ENABLED: "false",
         SKETCH_DEPTH: "4",

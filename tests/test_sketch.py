@@ -533,9 +533,7 @@ class TestExports:
             )
         bus2 = TelemetryBus(enabled=True, sketch_k=5)
         assert bus2.export_topk_k == 5
-        # Deprecated aliases still read the renamed fields.
-        assert bus2.sketch_k == bus2.blocked_topk_k == 5
-        assert bus2.sketch is bus2.blocked_sketch
+        assert bus2.blocked_topk_k == 5
 
 
 class TestColumnarKeyPath:

@@ -150,7 +150,9 @@ class TestFlightRecorder:
                 eng.flush()
             assert eng.telemetry.spans() == []
             assert eng.telemetry.counters_snapshot()["flushes"] == 0
-            assert eng.telemetry.sketch.topk(5) == []
+            assert eng.telemetry.blocked_sketch.topk(5) == []
+            # The breakdown reads the (unrecorded) spans: all zeros.
+            assert set(eng.last_flush_host_ms.values()) == {0.0}
         finally:
             config.set(config.TELEMETRY_ENABLED, "true")
 
@@ -195,7 +197,7 @@ class TestBlockedSketch:
         got = dict(engine.telemetry.last_blocked_topk)
         assert got == exact
         # The running sketch agrees too (single flush, no merging yet).
-        sk = {k: c for k, c, _ in engine.telemetry.sketch.topk(8)}
+        sk = {k: c for k, c, _ in engine.telemetry.blocked_sketch.topk(8)}
         for k, w in exact.items():
             assert sk[k] == w
 
@@ -294,6 +296,7 @@ class TestExports:
             "sentinel_engine_pipeline_mean_inflight",
             "sentinel_engine_last_flush_encode_ms",
             "sentinel_engine_last_flush_dispatch_ms",
+            "sentinel_engine_last_flush_fill_ms",
             "sentinel_engine_flushes_total",
             "sentinel_engine_coalesced_fallback_total",
             "sentinel_engine_param_cache_hits_total",
@@ -404,3 +407,186 @@ class TestOverhead:
         t_off = run(False)
         t_on = run(True)
         assert t_on <= t_off * 1.02 + 0.01, (t_on, t_off)
+
+
+# Every phase span and the phase that contains it.
+_PHASE_PARENT = {
+    "sentinel.flush.drain": "sentinel.flush",
+    "sentinel.flush.swap": "sentinel.flush",
+    "sentinel.encode": "sentinel.flush",
+    "sentinel.encode.staging": "sentinel.encode",
+    "sentinel.encode.shaping": "sentinel.encode",
+    "sentinel.encode.param": "sentinel.encode",
+    "sentinel.dispatch": "sentinel.flush",
+    "sentinel.fetch": "sentinel.flush",
+    "sentinel.fill": "sentinel.flush",
+    "sentinel.post_flush": "sentinel.flush",
+    "sentinel.submit.param": "sentinel.submit",
+}
+# FlushSpan field that reads each timed phase.
+_PHASE_FIELD = {
+    "sentinel.flush.swap": "swap_ms",
+    "sentinel.encode": "encode_ms",
+    "sentinel.encode.shaping": "shaping_encode_ms",
+    "sentinel.encode.param": "param_encode_ms",
+    "sentinel.dispatch": "dispatch_ms",
+    "sentinel.fetch": "fetch_ms",
+    "sentinel.fill": "fill_ms",
+}
+
+
+def _phase_world(engine):
+    """A tiny engine with a rate-limiter rule and a hot-param rule."""
+    from sentinel_tpu.models import constants as C
+
+    st.flow_rule_manager.load_rules([
+        st.FlowRule("ph_rl", count=20,
+                    control_behavior=C.CONTROL_BEHAVIOR_RATE_LIMITER,
+                    max_queueing_time_ms=200),
+        st.FlowRule("ph_p", count=1e9),
+    ])
+    engine.set_param_rules(
+        {"ph_p": [ParamFlowRule("ph_p", param_idx=0, count=3)]}
+    )
+
+
+def _phase_flush(engine, clock, t: int):
+    """Exits of an earlier group, two bulk groups, one flush; returns
+    the verdict columns."""
+    clock.set_ms(t)
+    g0 = engine.submit_bulk("ph_p", 4, ts=t, args_column=[("z",)] * 4)
+    engine.flush()
+    engine.submit_exit_bulk(g0.rows, g0.admitted_count or 1, rt=5, resource="ph_p")
+    g1 = engine.submit_bulk("ph_rl", 16, ts=t)
+    g2 = engine.submit_bulk(
+        "ph_p", 16, ts=t, args_column=[(f"k{i % 5}",) for i in range(16)]
+    )
+    engine.flush()
+    return [np.asarray(a) for g in (g1, g2)
+            for a in (g.admitted, g.reason, g.wait_ms)]
+
+
+class TestProfilerPhases:
+    def _trace(self, engine, clock, tmp_path):
+        import jax
+
+        _phase_world(engine)
+        _phase_flush(engine, clock, 1000)  # compiles every shape
+        with jax.profiler.trace(str(tmp_path)):
+            verdicts = _phase_flush(engine, clock, 2000)
+        from jax.profiler import ProfileData
+        import glob
+
+        (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+        lines = {}
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("sentinel."):
+                        lines.setdefault(line.name, []).append((
+                            ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                            dict(ev.stats),
+                        ))
+        return verdicts, lines
+
+    def test_phase_spans_nest_and_share_the_flush_seq(
+        self, manual_clock, engine, tmp_path
+    ):
+        _, lines = self._trace(engine, manual_clock, tmp_path)
+        assert len(lines) == 1, list(lines)  # one flushing thread
+        (events,) = lines.values()
+        # Two flushes are profiled (g0's, then the measured one).
+        flush = [e for e in events if e[0] == "sentinel.flush"]
+        seq = flush[-1][3]["flush"]
+        assert flush[-1][3]["step_num"] == seq
+        mine = [e for e in events if e[3].get("flush") == seq]
+        names = {e[0] for e in mine}
+        assert set(_PHASE_PARENT) | {"sentinel.flush", "sentinel.submit"} <= names
+        for name, t0, t1, _ in mine:
+            parent = _PHASE_PARENT.get(name)
+            if parent is None:
+                continue
+            assert any(p[0] == parent and p[1] <= t0 and t1 <= p[2]
+                       for p in mine), (name, parent)
+        shaping = next(e for e in mine if e[0] == "sentinel.encode.shaping")
+        assert shaping[3]["items"] == 16 and shaping[3]["padded"] == 16
+        assert next(e for e in mine if e[0] == "sentinel.fill")[3]["groups"] == 2
+
+    def test_flush_span_phases_equal_profiler_spans(
+        self, manual_clock, engine, tmp_path
+    ):
+        _, lines = self._trace(engine, manual_clock, tmp_path)
+        (events,) = lines.values()
+        span = engine.telemetry.spans()[-1]
+        seq = max(e[3]["flush"] for e in events)
+        for name, field in _PHASE_FIELD.items():
+            (ev,) = [e for e in events if e[0] == name and e[3]["flush"] == seq]
+            assert getattr(span, field) == pytest.approx(
+                (ev[2] - ev[1]) / 1e6, abs=0.05
+            ), name
+        assert span.settle_ms == pytest.approx(span.fetch_ms + span.fill_ms)
+        assert span.shaping_items == 16 and span.groups == 2
+        view = engine.last_flush_host_ms
+        assert view["kernel_ms"] == pytest.approx(span.dispatch_ms + span.fetch_ms)
+        assert view["fill_ms"] == pytest.approx(span.fill_ms)
+
+    def test_verdicts_identical_with_profiler_on_and_off(
+        self, manual_clock, engine, tmp_path
+    ):
+        from sentinel_tpu.core import api
+
+        traced, _ = self._trace(engine, manual_clock, tmp_path)
+        manual_clock.set_ms(0)
+        api.reset(clock=manual_clock)
+        eng = api.get_engine()
+        _phase_world(eng)
+        _phase_flush(eng, manual_clock, 1000)
+        plain = _phase_flush(eng, manual_clock, 2000)
+        for a, b in zip(traced, plain):
+            np.testing.assert_array_equal(a, b)
+
+
+def test_kernel_stage_scopes_in_lowering(monkeypatch):
+    """Every stage of the flush kernel carries its ``sentinel/<stage>``
+    scope in the lowered program's op metadata."""
+    from sentinel_tpu.models import constants as C
+    from sentinel_tpu.runtime import engine as E
+    from sentinel_tpu.runtime import flush as F
+    from sentinel_tpu.rules.system_manager import SystemConfig
+    from sentinel_tpu.utils.clock import ManualClock
+
+    got = {}
+
+    class _Captured(Exception):
+        pass
+
+    def record(*args, **kw):
+        got.update(args=args, kw=kw)
+        raise _Captured
+
+    monkeypatch.setattr(E, "flush_step_full_jit", record)
+    config.set(config.SKETCH_ENABLED, "true")
+    try:
+        eng = E.Engine(clock=ManualClock(start_ms=10_000))
+    finally:
+        config.set(config.SKETCH_ENABLED,
+                   config.DEFAULTS[config.SKETCH_ENABLED])
+    eng.set_flow_rules([
+        st.FlowRule("rl", count=10, control_behavior=C.CONTROL_BEHAVIOR_RATE_LIMITER),
+    ])
+    eng.set_degrade_rules([st.DegradeRule("rl", grade=1, count=0.5, time_window=1)])
+    eng.set_param_rules({"api": [st.ParamFlowRule("api", param_idx=0, count=3)]})
+    eng.set_system_config(SystemConfig(qps=1000.0))
+    g = eng.submit_bulk("rl", 8)
+    eng.submit_exit_bulk(g.rows, 4, rt=5, resource="rl")
+    eng.submit_bulk("api", 8, args_column=[(f"k{i}",) for i in range(8)])
+    with pytest.raises(_Captured):
+        eng.flush()
+    text = F.flush_step_full_jit.lower(*got["args"], **got["kw"]).as_text(
+        debug_info=True
+    )
+    for stage in ("roll", "exits", "system", "param", "flow", "shaping",
+                  "degrade", "account", "topk", "sketch"):
+        assert f"sentinel/{stage}" in text, stage

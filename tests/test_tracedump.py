@@ -42,20 +42,33 @@ class TestTraceFormat:
                 assert e["ts"] >= 0.0 and e["dur"] > 0.0
                 assert "trace_id" in e["args"] and "flush_seq" in e["args"]
             elif e["ph"] == "X":
-                assert e["name"] in ("encode", "dispatch", "inflight")
+                assert e["name"] in (
+                    "encode", "staging", "shaping", "param", "dispatch",
+                    "fetch", "fill", "inflight",
+                )
                 assert e["ts"] >= 0.0 and e["dur"] >= 0.0
                 assert "flush_id" in e["args"]
 
     def test_same_tid_slices_do_not_overlap(self, depth2_trace):
+        """Slices on one tid never overlap partially: a phase part
+        (staging, shaping, param inside encode) nests wholly inside its
+        parent, every other pair is disjoint."""
         events = [e for e in depth2_trace[0]["traceEvents"] if e["ph"] == "X"]
         by_tid = {}
         for e in events:
             by_tid.setdefault(e["tid"], []).append((e["ts"], e["ts"] + e["dur"]))
         for tid, spans in by_tid.items():
-            spans.sort()
-            for (s0, e0), (s1, _e1) in zip(spans, spans[1:]):
+            # Longest first at a shared start, so a parent precedes the
+            # parts it contains.
+            spans.sort(key=lambda ab: (ab[0], -ab[1]))
+            open_ends = []
+            for s0, e0 in spans:
                 # 1 µs grace for float rounding at shared boundaries.
-                assert s1 >= e0 - 1e-3, (tid, (s0, e0), s1)
+                while open_ends and open_ends[-1] <= s0 + 1e-3:
+                    open_ends.pop()
+                if open_ends:
+                    assert e0 <= open_ends[-1] + 1e-3, (tid, (s0, e0), open_ends[-1])
+                open_ends.append(e0)
 
     def test_request_flow_events_link_to_deciding_flush(self, depth2_trace):
         """Acceptance: the dump contains request→flush flow arrows in
