@@ -27,9 +27,15 @@ implementations share one transition function:
 
 Both reproduce the reference's per-request logic step for step —
 including the per-second token re-fill (syncToken) — so they are exact
-even when a batch spans multiple seconds. The vectorized DEFAULT path
-never pays for any of this: when no shaping rules are loaded the module
-is never entered.
+even when a batch spans multiple seconds. A third, ``rounds == -1``,
+is the closed form for batches whose items all share ONE timestamp and
+ONE acquire ≥ 1 (the columnar-bulk shape), for all three behaviors: at
+one ts, syncToken can fire only at a segment's first item, so the
+warm-up state is fixed after it, each warm-up verdict is elementwise
+and each pacer's cost is one constant per rule — rank math, no
+recurrence. The scan and rounds paths serve mixed-timestamp batches.
+The vectorized DEFAULT path never pays for any of this: when no shaping
+rules are loaded the module is never entered.
 
 Numerics: Java computes in float64; the math uses float32 for the
 warm-up slope (divergence only possible exactly at a threshold
@@ -73,8 +79,7 @@ class ShapingBatch(NamedTuple):
 def _pacer_cost(acq_f, acq_i, cnt, c1):
     """RateLimiter pacing cost in ms: the host-precomputed exact cost1
     for the acquire==1 fast path, else round(acquire/count*1000).
-    Shared by the recurrence and the closed-form rank path — their
-    bit-exact parity depends on one cost formula."""
+    :func:`mirror_pacer_cost` is its host twin — one cost formula."""
     cost_generic = jnp.floor(acq_f / jnp.maximum(cnt, 1e-9) * 1000.0 + 0.5)
     return jnp.where(acq_i == 1, c1.astype(jnp.float32), cost_generic).astype(
         jnp.int32
@@ -85,7 +90,9 @@ def _transition(latest, stored, lastfill, x):
     """One item's controller decision + state update, vector-friendly
     (works elementwise on arrays of items as well as on scan scalars).
     Invalid items are identity on state and ok=True.
-    Returns (ok, wait_out, latest', stored', lastfill')."""
+    Returns (ok, wait_out, latest', stored', lastfill', cost): ``cost``
+    is the pacer cost in ms the item was charged (the warm-up-adjusted
+    one for WARM_UP_RATE_LIMITER), which the closed-form path reuses."""
     (valid, ts, acq_f, acq, passq, prevq, b, cnt, mq, c1, wn, mx, sl, rt) = x
 
     is_wu = (b == C.CONTROL_BEHAVIOR_WARM_UP) | (
@@ -146,7 +153,7 @@ def _transition(latest, stored, lastfill, x):
     latest3 = jnp.where(valid & is_pacer, latest2, latest)
     stored3 = jnp.where(valid, stored2, stored)
     lastfill3 = jnp.where(valid, lastfill2, lastfill)
-    return ok, wait_out, latest3, stored3, lastfill3
+    return ok, wait_out, latest3, stored3, lastfill3, cost
 
 
 def run_shaping(
@@ -164,10 +171,10 @@ def run_shaping(
     ``rounds`` (static): host-known upper bound on items-per-rule in
     this batch — picks the vectorized rounds path; 0 falls back to the
     sequential ``lax.scan`` (see module docstring); −1 selects the
-    closed-form pacer rank path, which is ONLY valid when the host
-    verified every item is a plain RATE_LIMITER at one ts with one
-    acquire ≥ 1 (Engine._shaping_rounds_for owns that predicate —
-    run_shaping does not re-validate).
+    closed form, which is ONLY valid when the host verified that every
+    item shares one ts and one acquire ≥ 1, whatever the behaviors
+    (Engine._shaping_rounds_for owns that predicate — run_shaping does
+    not re-validate).
 
     The three behaviors (reference files in module docstring):
 
@@ -218,19 +225,33 @@ def run_shaping(
     new_grp = jnp.concatenate([ones, gid_s[1:] != gid_s[:-1]])
 
     if rounds == -1:
-        # Closed-form pacer path (host-selected when EVERY item is a
-        # plain RATE_LIMITER at ONE timestamp with ONE acquire ≥ 1 —
-        # the columnar-bulk shape): with a single ts per rule, at most
-        # the FIRST grant can be immediate (it pins latest to ts), and
-        # each further grant queues exactly one more ``cost`` out, so
-        # the r-th grant's wait is a closed form of the segment-start
-        # state and admission is prefix-monotone rank math — any
-        # per-rule multiplicity in O(sort), no unroll, no scan.
+        # Closed-form path (host-selected when EVERY item shares ONE
+        # timestamp and ONE acquire ≥ 1 — the columnar-bulk shape).
+        # Warm-up state changes only in syncToken, which fires only
+        # when sec > lastfill: within a segment that is at most its
+        # first item, after which lastfill == sec. So every item can
+        # see the segment-start state and the FIRST item's prevq (a
+        # gather, in case one rule's items carry different check
+        # rows): one elementwise _transition then gives each item the
+        # post-sync stored/lastfill, its WARM_UP verdict (its own
+        # passq) and its pacer cost, constant over the segment.
         idx = jnp.arange(s, dtype=jnp.int32)
         seg_start = jax.lax.cummax(jnp.where(new_grp, idx, 0))
         r1 = idx - seg_start + 1  # rank within segment, 1-indexed
+        items_first = items[:5] + (prevq_s[seg_start],) + items[6:]
+        ok_t, _, _, stored_t, lastfill_t, cost = _transition(
+            seg_latest, seg_stored, seg_lastfill, items_first
+        )
+        is_pacer = (beh == C.CONTROL_BEHAVIOR_RATE_LIMITER) | (
+            beh == C.CONTROL_BEHAVIOR_WARM_UP_RATE_LIMITER
+        )
 
-        cost = _pacer_cost(acq_s, acq_i, count, cost1)
+        # Pacer behaviors: with a single ts per rule, at most the FIRST
+        # grant can be immediate (it pins latest to ts), and each
+        # further grant queues exactly one more ``cost`` out, so the
+        # r-th grant's wait is a closed form of the segment-start state
+        # and admission is prefix-monotone rank math — any per-rule
+        # multiplicity in O(sort), no unroll, no scan.
         latest0 = seg_latest
         imm0 = latest0 + cost <= ts_s
         gate = count > 0
@@ -250,47 +271,40 @@ def run_shaping(
             jnp.where(latest0 - ts_s <= maxq, big, 0),
         )
         cap = jnp.where(gate, jnp.where(imm0, g_imm, g_queue), 0)
-        ok_s = (valid_s & (r1 <= cap)) | ~valid_s
+        # WARM_UP items keep _transition's elementwise verdict, no wait
+        # and no pacer state.
+        ok_s = jnp.where(is_pacer, (r1 <= cap) | ~valid_s, ok_t)
         # Waits only for admitted items, whose rank×cost is bounded by
         # maxq (+ts−latest0) and cannot overflow; blocked lanes may
         # wrap but are masked to 0.
         wait_r = jnp.where(imm0, (r1 - 1) * cost, latest0 + r1 * cost - ts_s)
-        wait_out_s = jnp.where(valid_s & ok_s & (wait_r > 0), wait_r, 0)
+        wait_out_s = jnp.where(
+            valid_s & is_pacer & ok_s & (wait_r > 0), wait_r, 0
+        )
         granted_here = jnp.minimum(r1, cap)
-        latest_here = jnp.where(
-            granted_here > 0,
+        latest_s = jnp.where(
+            is_pacer & (granted_here > 0),
             jnp.where(
                 imm0, ts_s + (granted_here - 1) * cost,
                 latest0 + granted_here * cost,
             ),
             latest0,
         )
-        seg_end = jnp.concatenate(
-            [gid_s[1:] != gid_s[:-1], jnp.ones((1,), dtype=bool)]
-        ) & valid_s
-        scatter_gid = jnp.where(seg_end, gid_c, jnp.int32(nr))
-        new_dyn = FlowRuleDynState(
-            latest_passed_time=flow_dyn.latest_passed_time.at[scatter_gid].set(
-                latest_here, mode="drop"
-            ),
-            # Warm-up columns untouched: no WARM_UP items are eligible.
-            stored_tokens=flow_dyn.stored_tokens,
-            last_filled_time=flow_dyn.last_filled_time,
+        stored_s, lastfill_s = stored_t, lastfill_t
+    else:
+        def transition(states, item_vals):
+            latest, stored, lastfill = states
+            ok, wait_out, l2, s2, f2, _cost = _transition(
+                latest, stored, lastfill, item_vals
+            )
+            return (ok, wait_out), (l2, s2, f2)
+
+        from sentinel_tpu.rules.recurrence import run_segmented
+
+        ok_s, wait_out_s, (latest_s, stored_s, lastfill_s) = run_segmented(
+            new_grp, (seg_latest, seg_stored, seg_lastfill), items, transition,
+            rounds,
         )
-        ok_out = jnp.ones((s,), dtype=bool).at[p_s].set(ok_s)
-        wait_out = jnp.zeros((s,), dtype=jnp.int32).at[p_s].set(wait_out_s)
-        return new_dyn, ok_out, wait_out
-
-    def transition(states, item_vals):
-        latest, stored, lastfill = states
-        ok, wait_out, l2, s2, f2 = _transition(latest, stored, lastfill, item_vals)
-        return (ok, wait_out), (l2, s2, f2)
-
-    from sentinel_tpu.rules.recurrence import run_segmented
-
-    ok_s, wait_s, (latest_s, stored_s, lastfill_s) = run_segmented(
-        new_grp, (seg_latest, seg_stored, seg_lastfill), items, transition, rounds
-    )
 
     # Write final per-rule state back at segment ends (last write wins).
     seg_end = jnp.concatenate(
@@ -309,7 +323,7 @@ def run_shaping(
 
     # Un-sort results back to the caller's slot order.
     ok_out = jnp.ones((s,), dtype=bool).at[p_s].set(ok_s)
-    wait_out = jnp.zeros((s,), dtype=jnp.int32).at[p_s].set(wait_s)
+    wait_out = jnp.zeros((s,), dtype=jnp.int32).at[p_s].set(wait_out_s)
     return new_dyn, ok_out, wait_out
 
 
@@ -432,9 +446,11 @@ def mirror_pacer_bulk(
     ranks: "np.ndarray",
 ) -> Tuple["np.ndarray", "np.ndarray", int]:
     """Closed-form host pacer for one bulk group's RATE_LIMITER slot —
-    the host twin of the kernel's ``rounds == -1`` rank path (same
-    preconditions: ONE timestamp, ONE acquire >= 1 per row, plain
-    RATE_LIMITER; the speculative tier's predicate owns that check).
+    the host twin of the kernel's ``rounds == -1`` rank path for the
+    plain pacer (ONE timestamp, ONE acquire >= 1 per row, plain
+    RATE_LIMITER only — narrower than the kernel's, which also takes
+    the warm-up behaviors; the speculative tier's predicate owns that
+    check).
     ``ranks`` is the 1-indexed grant rank of each still-live row.
     Returns ``(ok, wait_ms, latest')``."""
     n = ranks.shape[0]

@@ -4511,23 +4511,19 @@ class Engine:
             flat_pos=jnp.asarray(_p(flat_pos)),
             ts=jnp.asarray(_p(ts)),
             acquire=jnp.asarray(_p(acquire, 1)),
-        ), self._shaping_rounds_for(gid, ts, acquire, findex), total
+        ), self._shaping_rounds_for(gid, ts, acquire), total
 
     @staticmethod
-    def _shaping_rounds_for(gid, ts, acquire, findex: FlowIndex) -> int:
-        """Host-known shaping execution mode: −1 selects the closed-form
-        pacer path (every item a plain RATE_LIMITER at one ts with one
-        acquire ≥ 1); otherwise the pow2 rounds bound (0 = scan)."""
+    def _shaping_rounds_for(gid, ts, acquire) -> int:
+        """Host-known shaping execution mode: −1 selects the closed form
+        (every item at one ts with one acquire ≥ 1, any mix of the three
+        shaping behaviors — the columnar-bulk shape); otherwise the pow2
+        rounds bound (0 = scan), which mixed-timestamp batches keep."""
         if (
             gid.shape[0] > 0
             and ts.min() == ts.max()
             and acquire.min() == acquire.max()
             and acquire.min() >= 1
-            and all(
-                (r := findex.rule_of_gid(int(g))) is not None
-                and r.control_behavior == C.CONTROL_BEHAVIOR_RATE_LIMITER
-                for g in np.unique(gid)
-            )
         ):
             return -1
         return _rounds_bucket(gid)

@@ -829,12 +829,13 @@ class HostFallbackAdmitter:
     # bulk admission (vectorized)
     # ------------------------------------------------------------------
     def bulk_shaping_servable(self, g, findex) -> bool:
-        """The bulk closed-form preconditions — the same predicate as
-        Engine._shaping_rounds_for's ``-1`` path: every shaping slot a
-        plain RATE_LIMITER, ONE distinct ts, ONE acquire >= 1. The
-        speculative tier declines non-servable shaped groups to the
-        device; the degraded fill (no device to decline to) falls back
-        to the PR-5 plain-bucket stance for them."""
+        """The host closed-form preconditions: every shaping slot a
+        plain RATE_LIMITER, ONE distinct ts, ONE acquire >= 1. Narrower
+        than Engine._shaping_rounds_for's ``-1`` path, which also takes
+        the warm-up behaviors: ``shaping.mirror_pacer_bulk`` serves only
+        the plain pacer. The speculative tier declines non-servable
+        shaped groups to the device; the degraded fill (no device to
+        decline to) falls back to the plain-bucket stance for them."""
         sg = findex.shaping_gids
         if not sg or not any(gid in sg for gid, _crow in g.slots):
             return True
