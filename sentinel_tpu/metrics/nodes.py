@@ -196,32 +196,88 @@ def waiting_tokens(state: StatsState, now: jax.Array) -> jax.Array:
     return jnp.sum(jnp.where(future, state.future_pass, 0), axis=1)
 
 
-def apply_updates(
+def _segmented_min(start: jax.Array, v: jax.Array) -> jax.Array:
+    """Running minimum of ``v`` that restarts wherever ``start`` is set."""
+
+    def comb(a, b):
+        fa, va = a
+        fb, vb = b
+        return fa | fb, jnp.where(fb, vb, jnp.minimum(va, vb))
+
+    return jax.lax.associative_scan(comb, (start, v))[1]
+
+
+def apply_run_updates(
     state: StatsState,
-    rows: jax.Array,  # int32 [M]
-    ts: jax.Array,  # int32 [M]
-    deltas: jax.Array,  # int32 [M, NUM_EVENTS]
-    rt_sample: Optional[jax.Array],  # int32 [M] or None
-    thread_delta: jax.Array,  # int32 [M]
-    mask: jax.Array,  # bool [M]
-    minute_deltas: Optional[jax.Array] = None,
+    rows: jax.Array,  # int32 [N, 4] node rows per entry, -1 = none
+    ts: jax.Array,  # int32 [N]
+    valid: jax.Array,  # bool [N]
+    run_end: Optional[jax.Array],  # int32 [G] last entry of each run, -1 = padding
+    deltas: Dict[int, jax.Array],  # MetricEvent -> int32 [N], second window
+    thread_delta: jax.Array,  # int32 [N]
+    rt_sample: Optional[jax.Array] = None,  # int32 [N]
+    minute_deltas: Optional[Dict[int, jax.Array]] = None,
 ) -> StatsState:
-    """One scatter pass over both windows + the thread gauge.
+    """Account a batch on both windows and the thread gauge, one record
+    per *run* and node row.
+
+    A run is a contiguous span of entries that share all four node rows
+    and one ``ts`` (the host's run table, ``FlushBatch.e_run_end``); its
+    entries land in the same (row, bucket) keys, so their deltas can be
+    summed before the fan-out to the rows. Each run's sums are two reads
+    of the entries' prefix sums (invalid entries count 0), its RT sample
+    the minimum over its valid entries; :func:`metric_array.update` then
+    merges 4·G records instead of 4·N. Integer sums reassociate exactly
+    and a run has one window, so every tensor is bit-identical to one
+    record per entry. A run with no valid entry writes nothing (it rolls
+    no bucket). ``run_end`` None makes every entry a run of its own.
 
     ``minute_deltas`` overrides the event deltas for the minute window —
     occupied entries diverge between windows (addOccupiedPass writes
     PASS + OCCUPIED_PASS to the minute counter only, reference:
     node/StatisticNode.java:343-346, while the second window's pass
     materialises when the borrowed window becomes current)."""
-    second = ma.update(SECOND_CFG, state.second, rows, ts, deltas, rt_sample, mask)
-    minute = ma.update(
-        MINUTE_CFG, state.minute, rows, ts,
-        deltas if minute_deltas is None else minute_deltas, rt_sample, mask,
+    n = ts.shape[0]
+    if run_end is None:
+        run_end = jnp.arange(n, dtype=jnp.int32)
+    minute = {} if minute_deltas is None else minute_deltas
+    cols = list(deltas.values()) + list(minute.values()) + [thread_delta, valid]
+    # Column-major [C, N]: the entry axis is the long, lane-dense one.
+    cs = jnp.cumsum(
+        jnp.stack([jnp.where(valid, c, 0).astype(jnp.int32) for c in cols]), axis=1
     )
-    rows_eff = jnp.where(mask, rows, 0).astype(jnp.int32)
-    thr = jnp.where(mask, thread_delta, 0).astype(jnp.int32)
-    threads = state.threads.at[rows_eff].add(thr, mode="drop")
-    return state._replace(second=second, minute=minute, threads=threads)
+    live = run_end >= 0
+    end = jnp.where(live, run_end, 0)
+    prev = jnp.concatenate([jnp.full((1,), -1, jnp.int32), run_end[:-1]])
+    sums = cs[:, end] - jnp.where(prev >= 0, cs[:, jnp.maximum(prev, 0)], 0)
+    run_ok = live & (sums[-1] > 0)
+
+    g = run_end.shape[0]
+    rows_f = rows[end].reshape(-1)  # [4G]
+    mask = (rows_f >= 0) & jnp.repeat(run_ok, 4)
+    ts_f = jnp.repeat(ts[end], 4)
+
+    def event_matrix(evs, at):
+        out = jnp.zeros((g, NUM_EVENTS), dtype=jnp.int32)
+        for i, ev in enumerate(evs):
+            out = out.at[:, ev].set(sums[at + i])
+        return jnp.repeat(out, 4, axis=0)
+
+    d_sec = event_matrix(list(deltas), 0)
+    d_min = event_matrix(list(minute), len(deltas)) if minute else d_sec
+    rt_f = None
+    if rt_sample is not None:
+        start = jnp.zeros((n,), dtype=bool).at[jnp.where(live, prev + 1, n)].set(
+            True, mode="drop"
+        )
+        run_min = _segmented_min(start, jnp.where(valid, rt_sample, jnp.int32(2**31 - 1)))
+        rt_f = jnp.repeat(run_min[end], 4)
+
+    second = ma.update(SECOND_CFG, state.second, rows_f, ts_f, d_sec, rt_f, mask)
+    minute_st = ma.update(MINUTE_CFG, state.minute, rows_f, ts_f, d_min, rt_f, mask)
+    thr = jnp.where(mask, jnp.repeat(sums[-2], 4), 0)
+    threads = state.threads.at[jnp.where(mask, rows_f, 0)].add(thr, mode="drop")
+    return state._replace(second=second, minute=minute_st, threads=threads)
 
 
 class NodeKind:

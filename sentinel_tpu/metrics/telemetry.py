@@ -107,6 +107,13 @@ class FlushSpan:
     shaping_rounds: int = 0  # pacer mode: -1 closed form, 0 scan, >0 rounds
     param_items: int = 0  # hot-param items encoded
     groups: int = 0  # bulk entry groups whose verdicts the fill fans out
+    # Statistics accounting runs (nodes.apply_run_updates): spans of
+    # entries / exits sharing node rows and ts, and their padded table
+    # lengths — the kernel merges 4 records per table slot.
+    runs: int = 0
+    runs_padded: int = 0
+    x_runs: int = 0
+    x_runs_padded: int = 0
     deferred: bool = False  # dispatched without fetching (pipelined/async)
     settled: bool = False
     arena_hits: int = 0
@@ -163,6 +170,10 @@ class FlushSpan:
             "shaping_rounds": self.shaping_rounds,
             "param_items": self.param_items,
             "groups": self.groups,
+            "runs": self.runs,
+            "runs_padded": self.runs_padded,
+            "x_runs": self.x_runs,
+            "x_runs_padded": self.x_runs_padded,
             "deferred": self.deferred,
             "settled": self.settled,
             "arena_hits": self.arena_hits,

@@ -96,7 +96,9 @@ def cluster_allocate(
 
 def batch_partition_specs(axis: str = "data"):
     """PartitionSpec pytree for a FlushBatch: entries/exits sharded over
-    the mesh, scalars replicated."""
+    the mesh, scalars replicated. The run tables split into equal
+    per-shard blocks of shard-local indices (Engine._encode_staging cuts
+    runs at shard boundaries)."""
     from jax.sharding import PartitionSpec as P
 
     from sentinel_tpu.runtime.flush import FlushBatch
@@ -121,6 +123,8 @@ def batch_partition_specs(axis: str = "data"):
         x_err=P(axis),
         x_thr=P(axis),
         x_dgid=P(axis, None),
+        e_run_end=P(axis),
+        x_run_end=P(axis),
     )
 
 

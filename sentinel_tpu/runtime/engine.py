@@ -526,6 +526,43 @@ def _rounds_bucket(keys: np.ndarray) -> int:
     return 1 if m <= 1 else 1 << (m - 1).bit_length()
 
 
+# Least run-table length: below it a shorter table saves the kernel
+# little, and every length is one more compiled shape.
+_RUN_TABLE_MIN = 4096
+
+
+def _run_table(
+    rows: np.ndarray, ts: np.ndarray, live: int, shards: int
+) -> Tuple[np.ndarray, int]:
+    """The run table of one staged side (entries or exits): the index of
+    the last entry of each maximal span of the first ``live`` entries
+    that share all four node rows and one ts (a bulk group, a single
+    op, or neighbours that happen to match), cut at shard boundaries.
+    Each shard's block holds its runs in shard-local indices, then -1;
+    the block length is a power of two, at least 1/16 of the shard and
+    ``_RUN_TABLE_MIN``, at most the shard — five lengths per shape at
+    most. Returns (int32 [shards * block], number of runs)."""
+    per = ts.shape[0] // shards
+    last = np.zeros(live, dtype=bool)
+    if live:
+        r64 = rows.view(np.int64)  # [n, 2]: two node rows per compare
+        last[:-1] = (
+            (ts[1:live] != ts[: live - 1])
+            | (r64[1:live, 0] != r64[: live - 1, 0])
+            | (r64[1:live, 1] != r64[: live - 1, 1])
+        )
+        last[per - 1 :: per] = True
+        last[-1] = True
+    ends = np.flatnonzero(last).astype(np.int32)
+    shard = ends // per
+    counts = np.bincount(shard, minlength=shards)
+    block = min(per, max(_pad_pow2(int(counts.max()), 1), per // 16, _RUN_TABLE_MIN))
+    table = np.full((shards, block), -1, dtype=np.int32)
+    first = np.cumsum(counts) - counts
+    table[shard, np.arange(ends.size) - first[shard]] = ends - shard * per
+    return table.reshape(-1), int(ends.size)
+
+
 def _weighted_rt(gx: "_BulkExitOp") -> int:
     """Count-weighted mean RT for aggregated completion callbacks — an
     unweighted mean would skew extensions that reconstruct total time
@@ -3440,10 +3477,12 @@ class Engine:
             )
         phase = self.telemetry.phase
         with phase("sentinel.encode", span, "encode_ms", flush=seq):
-            with phase("sentinel.encode.staging", flush=seq):
-                batch, staging, k, kd, now_host = self._encode_staging(
+            with phase("sentinel.encode.staging", flush=seq) as ph:
+                batch, staging, k, kd, now_host, runs = self._encode_staging(
                     entries, exits, bulk, bulk_exits, n_bulk, m_bulk
                 )
+                ph.set(runs=runs[0], runs_padded=runs[1], x_runs=runs[2],
+                       x_runs_padded=runs[3])
                 sysdev = self._system_device()
             with phase("sentinel.encode.shaping", span, "shaping_encode_ms",
                        flush=seq) as ph:
@@ -3514,6 +3553,8 @@ class Engine:
             span.shaping_rounds = sh_rounds
             span.param_items = p_items
             span.groups = len(bulk)
+            (span.runs, span.runs_padded, span.x_runs,
+             span.x_runs_padded) = runs
         # Flight recorder: spill the chunk's inputs BEFORE dispatch (a
         # dispatch fault must not lose the traffic that caused it); the
         # verdicts follow from the fill path via the one-shot token.
@@ -3801,11 +3842,13 @@ class Engine:
         bulk_exits: List[_BulkExitOp],
         n_bulk: int,
         m_bulk: int,
-    ) -> Tuple[FlushBatch, List[Tuple[tuple, tuple]], int, int, int]:
+    ) -> Tuple[FlushBatch, List[Tuple[tuple, tuple]], int, int, int,
+               Tuple[int, int, int, int]]:
         """Entry and exit staging of one chunk: the padded host columns
-        (arena-pooled) and their FlushBatch device transfers. Returns
-        (batch, staging buffers to pool after the fetch, slot width k,
-        degrade width kd, the flush's engine-clock now)."""
+        (arena-pooled), the run tables and their FlushBatch device
+        transfers. Returns (batch, staging buffers to pool after the
+        fetch, slot width k, degrade width kd, the flush's engine-clock
+        now, (entry runs, their table length, exit runs, theirs))."""
         # Pow2 padding is shard-divisible on any power-of-two mesh once
         # raised to at least n_shards (enable_mesh enforces pow2).
         n = max(_pad_pow2(len(entries) + n_bulk, 8), self._n_shards)
@@ -3942,6 +3985,10 @@ class Engine:
                 x_dgid[sl, j] = dg
             off_x += g.n
 
+        e_runs, n_runs = _run_table(
+            e_rows, e_ts, len(entries) + n_bulk, self._n_shards
+        )
+        x_runs, nx_runs = _run_table(x_rows, x_ts, nx + m_bulk, self._n_shards)
         now_host = self.clock.now_ms()
         batch = FlushBatch(
             now=jnp.int32(now_host),
@@ -3963,6 +4010,8 @@ class Engine:
             x_err=jnp.asarray(x_err),
             x_thr=jnp.asarray(x_thr),
             x_dgid=jnp.asarray(x_dgid),
+            e_run_end=jnp.asarray(e_runs),
+            x_run_end=jnp.asarray(x_runs),
         )
         # Staging buffers go back to the arena only after this chunk's
         # results are fetched — jnp.asarray may have zero-copied them
@@ -3971,7 +4020,8 @@ class Engine:
         if self._arena is not None:
             staging.append((ekey, ebufs))
             staging.append((xkey, xbufs))
-        return batch, staging, k, kd, now_host
+        runs = (n_runs, e_runs.shape[0], nx_runs, x_runs.shape[0])
+        return batch, staging, k, kd, now_host, runs
 
     def _degraded_chunk(
         self, fo, entries, exits, bulk, bulk_exits, defer, span=None,

@@ -47,13 +47,13 @@ import jax
 import jax.numpy as jnp
 
 from sentinel_tpu.core import errors as E
-from sentinel_tpu.metrics.events import MetricEvent, NUM_EVENTS
+from sentinel_tpu.metrics.events import MetricEvent
 from sentinel_tpu.metrics import metric_array as ma
 from sentinel_tpu.metrics import nodes as _ncfg
 from sentinel_tpu.metrics.nodes import (
     MINUTE_CFG,
     StatsState,
-    apply_updates,
+    apply_run_updates,
     waiting_tokens,
 )
 from sentinel_tpu.models import constants as C
@@ -102,6 +102,12 @@ class FlushBatch(NamedTuple):
     x_err: jax.Array  # int32 [M] exception delta
     x_thr: jax.Array  # int32 [M] thread delta (-1 exit, 0 trace)
     x_dgid: jax.Array  # int32 [M, KD] degrade-rule ids (breaker completion)
+    # --- run tables (statistics accounting, nodes.apply_run_updates) ---
+    # Index of the last entry (exit) of each run of entries that share
+    # all four node rows and one ts, ascending, then -1 padding; under a
+    # mesh, per shard in shard-local indices. None: one run per entry.
+    e_run_end: Optional[jax.Array] = None  # int32 [G]
+    x_run_end: Optional[jax.Array] = None  # int32 [GX]
 
 
 class SystemDevice(NamedTuple):
@@ -490,14 +496,6 @@ def commit_borrow_slab(
     return stats._replace(future_pass=fut_pass, future_ws=fut_ws)
 
 
-def _scatter_cols(n: int, **cols: jax.Array) -> jax.Array:
-    """Build an int32 [n, NUM_EVENTS] delta matrix from named event columns."""
-    out = jnp.zeros((n, NUM_EVENTS), dtype=jnp.int32)
-    for name, v in cols.items():
-        out = out.at[:, MetricEvent[name]].set(v.astype(jnp.int32))
-    return out
-
-
 def system_check(
     stats: StatsState,
     sysdev: SystemDevice,
@@ -616,30 +614,22 @@ def apply_exit_phase(
     """
     if not with_exits:
         return stats, ddyn
-    m = batch.x_valid.shape[0]
 
     # ---- phase 1: exits + traces (StatisticSlot.exit:148+) ----
-    x_rows_f = batch.x_rows.reshape(-1)
-    x_mask = (x_rows_f >= 0) & jnp.repeat(batch.x_valid, 4)
-    x_ts_f = jnp.repeat(batch.x_ts, 4)
-    x_deltas = _scatter_cols(
-        4 * m,
-        SUCCESS=jnp.repeat(batch.x_count, 4),
-        RT=jnp.repeat(batch.x_rt, 4),
-        EXCEPTION=jnp.repeat(batch.x_err, 4),
-    )
     # min-RT tracked only for true exits (thread delta < 0) that carry
     # completions — not traces, and not the speculative tier's
     # thread-gauge compensation ops (count=0, thr=±n), whose rt=0 must
     # not write a bogus sample into the window minimum
     # (runtime/speculative.py reconciliation).
-    x_thr_f = jnp.repeat(batch.x_thr, 4)
     x_rt_sample = jnp.where(
-        (x_thr_f < 0) & (jnp.repeat(batch.x_count, 4) > 0),
-        jnp.repeat(batch.x_rt, 4),
-        _I32_MAX,
+        (batch.x_thr < 0) & (batch.x_count > 0), batch.x_rt, _I32_MAX
     )
-    stats = apply_updates(stats, x_rows_f, x_ts_f, x_deltas, x_rt_sample, x_thr_f, x_mask)
+    stats = apply_run_updates(
+        stats, batch.x_rows, batch.x_ts, batch.x_valid, batch.x_run_end,
+        {MetricEvent.SUCCESS: batch.x_count, MetricEvent.RT: batch.x_rt,
+         MetricEvent.EXCEPTION: batch.x_err},
+        batch.x_thr, rt_sample=x_rt_sample,
+    )
 
     # ---- phase 1b: breaker completions (DegradeSlot.exit:67-90) ----
     if with_degrade:
@@ -849,34 +839,27 @@ def flush_entries(
 
         # ---- phase 3: entry accounting (StatisticSlot.entry:64-120) ----
         if commit:
-            e_rows_f = batch.e_rows.reshape(-1)
-            e_mask = (e_rows_f >= 0) & jnp.repeat(batch.e_valid, 4)
-            adm4 = jnp.repeat(admitted, 4)
             # Occupied entries: thread acquire + OCCUPIED_PASS now; their
             # PASS materialises when the borrowed window becomes current
             # (StatisticSlot's PriorityWaitException branch + the
             # DefaultController addOccupiedPass call).
-            occ4 = jnp.repeat(occupied & admitted, 4)
-            acq4 = jnp.repeat(batch.e_acquire, 4)
-            e_deltas = _scatter_cols(
-                4 * n,
-                PASS=jnp.where(adm4 & ~occ4, acq4, 0),
-                BLOCK=jnp.where(adm4, 0, acq4),
-            )
+            occ = occupied & admitted
+            acq = batch.e_acquire
+            blocked = jnp.where(admitted, 0, acq)
             # Minute window: occupied entries count PASS + OCCUPIED_PASS
             # immediately (StatisticNode.addOccupiedPass writes both to
             # rollingCounterInMinute, node/StatisticNode.java:343-346); the
             # second window's pass arrives via the future slab instead.
-            e_deltas_min = _scatter_cols(
-                4 * n,
-                PASS=jnp.where(adm4, acq4, 0),
-                BLOCK=jnp.where(adm4, 0, acq4),
-                OCCUPIED_PASS=jnp.where(occ4, acq4, 0),
-            )
-            e_thr = jnp.where(adm4, 1, 0).astype(jnp.int32)
-            stats = apply_updates(
-                stats, e_rows_f, jnp.repeat(batch.e_ts, 4), e_deltas, None, e_thr, e_mask,
-                minute_deltas=e_deltas_min,
+            stats = apply_run_updates(
+                stats, batch.e_rows, batch.e_ts, batch.e_valid, batch.e_run_end,
+                {MetricEvent.PASS: jnp.where(admitted & ~occ, acq, 0),
+                 MetricEvent.BLOCK: blocked},
+                jnp.where(admitted, 1, 0),
+                minute_deltas={
+                    MetricEvent.PASS: jnp.where(admitted, acq, 0),
+                    MetricEvent.BLOCK: blocked,
+                    MetricEvent.OCCUPIED_PASS: jnp.where(occ, acq, 0),
+                },
             )
     with jax.named_scope("sentinel/topk"):
         blk_rows = blk_weight = None

@@ -513,6 +513,11 @@ class TestProfilerPhases:
         shaping = next(e for e in mine if e[0] == "sentinel.encode.shaping")
         assert shaping[3]["items"] == 16 and shaping[3]["padded"] == 16
         assert next(e for e in mine if e[0] == "sentinel.fill")[3]["groups"] == 2
+        # Accounting runs: the two groups over 32 entries, one exit group
+        # over 8 exits; tables this small are as long as their side.
+        staging = next(e for e in mine if e[0] == "sentinel.encode.staging")[3]
+        assert (staging["runs"], staging["runs_padded"]) == (2, 32)
+        assert (staging["x_runs"], staging["x_runs_padded"]) == (1, 8)
 
     def test_flush_span_phases_equal_profiler_spans(
         self, manual_clock, engine, tmp_path
