@@ -106,181 +106,204 @@ class Verdict(NamedTuple):
     speculative: bool = False
 
 
-class _PendingFetch:
-    """A dispatched flush whose device→host fetch was deferred
-    (``Engine.flush_async`` / the depth-K pipelined ``flush()``).
-    ``wait()`` materializes this record — and, FIFO, every older one —
-    filling the chunk's verdicts and running its post work (block log,
-    cluster-token releases). The record holds its own device-array
-    references and fill closure (with their own index snapshots), so
-    rule reloads after dispatch cannot skew attribution — and so a
-    drain can batch MANY records' device arrays into one coalesced
-    ``jax.device_get`` (``Engine._drain_pending``) instead of paying a
-    round-trip per record.
+class _ChunkResults(NamedTuple):
+    """What one chunk's settle fetches: the device arrays, then their
+    host values. A drain batches MANY chunks' records into one
+    coalesced ``jax.device_get`` (each separate fetch costs a full
+    device round-trip), which keeps the record type and its None
+    fields. The optional groups ride the same fetch when they apply."""
 
-    Each record has its own RLock: the blocking device round-trip and
-    any user callbacks in post work run WITHOUT the engine's deque
+    admitted: object
+    reason: object
+    slot_ok: object
+    wait_ms: object
+    sys_type: object
+    dslot_ok: object
+    # (rows, weight) of the blocked-resource top-K, folded on the device
+    # when telemetry is on (the sharded mesh flush has no fold).
+    blk: Optional[tuple] = None
+    # Post-flush breaker states, when breaker observers are registered.
+    breaker: object = None
+    # Settled pacer / warm-up dyn columns, for the speculative shaping
+    # mirror.
+    shaping: Optional[tuple] = None
+    # Sketch candidate (ids, counts), when the sketch tier is armed.
+    sketch: Optional[tuple] = None
+    # The device states, when a failover checkpoint is due.
+    ckpt: Optional[tuple] = None
+
+
+class _PendingChunk:
+    """One dispatched flush chunk, from its dispatch to its settled
+    verdicts. ``Engine._run_chunk`` returns one for every chunk, and
+    :meth:`settle` is the one path that settles it, whatever the
+    pipeline depth: depth 0 settles it right after its dispatch, under
+    the flush lock; the depth-K ``flush()`` and ``flush_async`` queue
+    it, and ``Engine._drain_pending`` settles it FIFO, usually from one
+    coalesced ``jax.device_get`` across many records. The record holds
+    its own device-array references and fill closure (with their own
+    index snapshots), so rule reloads after dispatch cannot skew
+    attribution.
+
+    ``seq`` is the chunk's flush sequence number (the fault injector's
+    key and the watchdog's attribution), or -1 when the engine was
+    degraded before the chunk got one: such a chunk never reached the
+    device. A record with no ``refs`` has nothing to fetch and settles
+    from policy.
+
+    A queued record has its own RLock: the blocking device round-trip
+    and any user callbacks in post work run WITHOUT the engine's deque
     lock held (concurrent dispatchers must not stall behind a fetch),
     and re-entrant materialization from a callback is a no-op."""
 
     __slots__ = (
-        "_engine", "_entries", "_bulk", "_exits", "_bulk_exits", "_refs",
-        "_fill", "_done", "_error", "_lock", "_staging", "_span", "_seq",
-        "_cap_tok",
+        "_engine", "_entries", "_exits", "_bulk", "_bulk_exits", "_refs",
+        "_fill", "_staging", "_span", "_seq", "_cap_tok", "_deferred",
+        "_done", "_error", "_lock",
     )
 
     def __init__(
-        self, engine: "Engine", entries: List["_EntryOp"], refs: tuple,
-        fill, staging: Optional[List[tuple]] = None, span=None,
-        bulk: Optional[List["BulkOp"]] = None, seq: int = -1,
-        exits: Optional[list] = None, bulk_exits: Optional[list] = None,
-        cap_tok=None,
+        self, engine: "Engine", entries: List["_EntryOp"], exits: list,
+        bulk: List["BulkOp"], bulk_exits: list,
+        refs: Optional[_ChunkResults] = None, fill=None,
+        staging: Optional[List[tuple]] = None, span=None, seq: int = -1,
+        cap_tok=None, deferred: bool = False,
     ) -> None:
         self._engine = engine
+        # The fill closure owns the chunk's normal processing; the op
+        # lists are kept so a policy fill can serve verdicts AND record
+        # the exits' device-gauge releases (replayed at restore)
+        # without the device results.
         self._entries = entries
-        # Bulk groups and exits of the chunk — the fill closure owns
-        # their normal processing; kept here so a failover quarantine
-        # can fill verdicts from policy AND record the exits' device-
-        # gauge releases (replayed at restore) without the device
-        # results.
-        self._bulk = bulk or []
-        self._exits = exits or []
-        self._bulk_exits = bulk_exits or []
+        self._exits = exits
+        self._bulk = bulk
+        self._bulk_exits = bulk_exits
         self._refs = refs  # device arrays awaiting their host fetch
-        self._fill = fill  # (fetched tuple) -> blocked_items
-        self._done = False
-        self._error: Optional[BaseException] = None
-        self._lock = threading.RLock()
+        self._fill = fill  # (fetched _ChunkResults) -> blocked_items
         # Arena staging buffers held until the fetch completes (the
         # dispatched computation may read them zero-copy until then).
         self._staging = staging or []
-        # Flight-recorder span closed at materialization (None when
-        # telemetry is disabled).
+        # Flight-recorder span closed at settle (None when telemetry
+        # is disabled).
         self._span = span
-        # Engine flush sequence number of the dispatched chunk — the
-        # fault injector's key and the watchdog's attribution.
         self._seq = seq
         # Capture-journal verdict token (one-shot): the fill closure
-        # spills verdicts on success; a quarantine spills the degraded
-        # policy verdicts instead.
+        # spills verdicts on success; a policy fill spills the degraded
+        # verdicts instead.
         self._cap_tok = cap_tok
+        # Queued (depth K / flush_async) rather than settled at once.
+        self._deferred = deferred
+        self._done = False
+        self._error: Optional[BaseException] = None
+        self._lock = threading.RLock()
 
-    def materialize(self, got: Optional[tuple] = None) -> None:
-        """Fetch + verdict fill + post work, exactly once. ``got`` is
-        an already-fetched result tuple from a coalesced batch
-        device_get (None → this record fetches its own). A failed
-        fetch is stored and re-raised to EVERY caller — a device
-        failure must never read as 'nothing admitted' — UNLESS failover
-        is armed: then the record is quarantined and its ops get policy
-        verdicts from the host fallback instead (degraded provenance;
-        runtime/failover.py). References to the chunk (closure, result
-        buffers, op lists) are dropped as soon as they are consumed."""
-        with self._lock:
-            if not self._done:
-                fo = self._engine.failover
-                if got is None and fo.armed and fo.degraded:
-                    # The engine degraded while this record waited:
-                    # don't touch the (possibly wedged) device again.
-                    self._quarantine_locked(fo)
-                    return
-                items: Optional[List[tuple]] = None
-                tele = self._engine.telemetry
-                t_fetch0 = time.perf_counter()
-                try:
-                    if got is None:
-                        t0 = time.perf_counter()
-                        got = self._engine._fetch_refs(
-                            self._refs, (self._seq,), self._span
-                        )
-                        self._engine._note_drain_ms(
-                            (time.perf_counter() - t0) * 1e3
-                        )
-                    with tele.phase("sentinel.fill", self._span, "fill_ms",
-                                    flush=self._seq, groups=len(self._bulk)):
-                        items = self._fill(got)
-                except BaseException as exc:
-                    if fo.armed:
-                        fo.trip("fetch", exc, self._seq)
-                        self._quarantine_locked(fo)
-                        return
-                    self._error = exc
-                finally:
-                    if not self._done:
-                        self._refs = None
-                        self._fill = None
-                        self._done = True
-                        # Staging returns to the arena only after a
-                        # SUCCESSFUL fetch (which proves the computation
-                        # consumed its possibly-zero-copy inputs); a
-                        # failed/interrupted fetch drops it to GC — the
-                        # computation may still be running.
-                        staging, self._staging = self._staging, []
-                        if (
-                            staging
-                            and self._error is None
-                            and self._engine._arena is not None
-                        ):
-                            self._engine._arena.give_all(staging)
-                span, self._span = self._span, None
-                if span is not None and self._error is None:
-                    # Close the flight-recorder span: for a coalesced
-                    # drain the fetch cost is in the drain histogram —
-                    # settle_t0 here times only this record's own fill
-                    # (plus its own fetch on the fallback path).
-                    tele.settle(span, t_fetch0, time.perf_counter())
-                entries, self._entries = self._entries, []
-                self._bulk = []
-                self._exits = []
-                self._bulk_exits = []
-                if self._error is None:
-                    # Post-work failures (log IO, release RPCs) surface
-                    # to this materializer only: the verdicts ARE
-                    # filled, so readers must not see them as poisoned.
-                    with tele.phase("sentinel.post_flush", flush=self._seq):
-                        self._engine._post_flush((entries, items or []))
-            if self._error is not None:
-                raise self._error
+    def settle(self, got: Optional[_ChunkResults] = None) -> List[tuple]:
+        """Settle the chunk, exactly once, and return its block-log
+        items; the caller runs the post work (``Engine._post_flush``).
+        The caller holds ``self._lock``, or owns the record alone
+        (depth 0). ``got`` is the record's results from a coalesced
+        drain; None → fetched here through ``Engine._fetch_refs``.
 
-    def _quarantine_locked(self, fo) -> None:
-        """Fill this record's ops from the fallback policy instead of
-        the lost device results. Caller holds ``self._lock`` and has
-        verified ``not self._done``. Staging is dropped to GC — the
-        dispatched computation may still be running (or wedged) and
-        could read the buffers zero-copy."""
-        entries, self._entries = self._entries, []
-        bulk, self._bulk = self._bulk, []
-        exits, self._exits = self._exits, []
-        bulk_exits, self._bulk_exits = self._bulk_exits, []
-        self._refs = None
-        self._fill = None
-        self._staging = []
+        With failover armed, a fetch or fill fault (or an engine already
+        DEGRADED before the fetch) trips failover and serves the chunk
+        from policy. Disarmed, the error is stored for every later
+        reader and raised — a device failure must never read as
+        'nothing admitted'. Staging returns to the arena only after a
+        SUCCESSFUL fetch, which proves the computation consumed its
+        possibly-zero-copy inputs; a failed fetch drops it to GC. The
+        chunk's references are dropped as they are consumed."""
+        eng = self._engine
+        fo = eng.failover
+        refs, fill, staging = self._refs, self._fill, self._staging
+        chunk = (self._entries, self._exits, self._bulk, self._bulk_exits)
+        span, cap_tok = self._span, self._cap_tok
         self._done = True
-        fo.note_quarantined()
-        span, self._span = self._span, None
+        self._refs = self._fill = self._span = self._cap_tok = None
+        self._staging = self._entries = self._exits = ()
+        self._bulk = self._bulk_exits = ()
+        if refs is None or (got is None and fo.armed and fo.degraded):
+            # Nothing dispatched, or the engine degraded while this
+            # record waited: don't touch the (possibly wedged) device.
+            return self._serve_policy(chunk, span, cap_tok)
+        tele = eng.telemetry
+        t0 = time.perf_counter()
+        try:
+            if got is None:
+                got = eng._fetch_refs(refs, (self._seq,), span)
+                if self._deferred:
+                    eng._note_drain_ms((time.perf_counter() - t0) * 1e3)
+            with tele.phase("sentinel.fill", span, "fill_ms",
+                            flush=self._seq, groups=len(chunk[2])):
+                items = fill(got)
+        except BaseException as exc:
+            if fo.armed:
+                fo.trip("fetch", exc, self._seq)
+                return self._serve_policy(chunk, span, cap_tok)
+            self._error = exc
+            raise
+        if staging and eng._arena is not None:
+            eng._arena.give_all(staging)
+        if span is not None:
+            # For a coalesced drain the fetch cost is in the drain
+            # histogram: the span's settle window holds this record's
+            # own fill (and its own fetch on the fallback path).
+            tele.settle(span, t0, time.perf_counter())
+        if not self._deferred and eng.ingest.armed:
+            # Settle-latency signal for the ingest deadline valve (a
+            # queued record's own fetch fed it through _note_drain_ms).
+            eng.ingest.note_settle_ms((time.perf_counter() - t0) * 1e3)
+        return items
+
+    def _serve_policy(self, chunk: tuple, span, cap_tok) -> List[tuple]:
+        """The one policy fill: the chunk's verdicts come from the host
+        fallback admitter (degraded provenance; runtime/failover.py)
+        instead of device results. A chunk with no flush seq never
+        reached the device, so its custom slot checks run here; any
+        other chunk's results were lost to a dispatch or fetch fault or
+        a quarantine (counted), and its custom slots already ran at
+        dispatch — a user hook never runs twice. Exits ride along so
+        their thread-gauge releases are recorded for the restore replay
+        (the chunk postdates any stored checkpoint)."""
+        eng = self._engine
+        fo = eng.failover
+        lost = self._seq >= 0
+        if lost:
+            fo.note_quarantined()
         if span is not None:
             span.quarantined = True
-            self._engine.telemetry.settle(
-                span, time.perf_counter(), time.perf_counter()
-            )
-        # Custom slot checks already ran when this chunk dispatched —
-        # never re-run user hooks on quarantine. Exits ride along so
-        # their thread-gauge releases are recorded for the restore
-        # replay (the chunk postdates any stored checkpoint).
+            now = time.perf_counter()
+            eng.telemetry.settle(span, now, now)
+        entries, exits, bulk, bulk_exits = chunk
         items = fo.fill_degraded(entries, exits, bulk, bulk_exits,
-                                 run_custom_slots=False)
-        cap_tok, self._cap_tok = self._cap_tok, None
-        if cap_tok is not None and self._engine.capture is not None:
-            self._engine.capture.note_verdicts(
-                cap_tok, entries, bulk, degraded=True
-            )
-        self._engine._post_flush((entries, items))
+                                 run_custom_slots=not lost)
+        if cap_tok is not None and eng.capture is not None:
+            eng.capture.note_verdicts(cap_tok, entries, bulk, degraded=True)
+        return items
+
+    def materialize(self, got: Optional[_ChunkResults] = None) -> None:
+        """Settle + post work (block log, cluster-token releases),
+        exactly once. A stored settle error re-raises to EVERY caller;
+        a post-work failure (log IO, release RPCs) surfaces to this
+        caller only — the verdicts ARE filled, so readers must not see
+        them as poisoned."""
+        with self._lock:
+            if self._done:
+                if self._error is not None:
+                    raise self._error
+                return
+            entries = self._entries
+            items = self.settle(got)
+            with self._engine.telemetry.phase("sentinel.post_flush",
+                                              flush=self._seq):
+                self._engine._post_flush((entries, items))
 
     def quarantine(self) -> None:
-        """Public quarantine entry (engine._quarantine_pending): fill
-        from policy unless already materialized."""
+        """Serve the chunk from policy unless already settled
+        (``Engine._quarantine_pending``, at a failover trip)."""
         with self._lock:
             if not self._done:
-                self._quarantine_locked(self._engine.failover)
+                self._refs = None
+                self.materialize()
 
     def wait(self) -> None:
         self._engine._drain_pending(upto=self)
@@ -299,8 +322,8 @@ class _EntryOp:
     prio: bool = False
     cluster_blocked_rule: Optional[object] = None  # token server said BLOCKED
     _verdict: Optional[Verdict] = field(default=None, repr=False)
-    # Deferred-fetch record when this op was flushed via flush_async.
-    _pending: Optional[_PendingFetch] = field(default=None, repr=False, compare=False)
+    # The chunk's record while it is queued (depth K, flush_async).
+    _pending: Optional[_PendingChunk] = field(default=None, repr=False, compare=False)
     # Held concurrency tokens acquired from the token service for
     # cluster THREAD-grade rules: [(service, token_id)] — released at
     # exit, or immediately if the entry is ultimately blocked.
@@ -423,7 +446,7 @@ class BulkOp:
     _admitted: Optional[np.ndarray] = field(default=None, repr=False)
     _reason: Optional[np.ndarray] = field(default=None, repr=False)
     _wait_ms: Optional[np.ndarray] = field(default=None, repr=False)
-    _pending: Optional[_PendingFetch] = field(default=None, repr=False, compare=False)
+    _pending: Optional[_PendingChunk] = field(default=None, repr=False, compare=False)
     # Group-level admission-trace stamp (bounded per-row records land
     # at verdict fill — see AdmissionTracer.record_bulk).
     trace: Optional[object] = field(default=None, repr=False, compare=False)
@@ -617,8 +640,8 @@ class _EncodeArena:
     staging buffer must never be mutated while a dispatched computation
     might still read it. Buffers therefore return to the pool only
     AFTER the chunk's device→host result fetch completes SUCCESSFULLY
-    (sync: end of ``_fill_results``; deferred: at ``_PendingFetch``
-    materialization) — ``jax.device_get`` of the results blocks until
+    (in the chunk record's settle, ``_PendingChunk.settle``, at any
+    pipeline depth) — ``jax.device_get`` of the results blocks until
     the computation that read the inputs has finished. A failed or
     interrupted fetch proves nothing, so its staging is dropped to GC
     instead of pooled. Until then the next chunk's
@@ -707,7 +730,7 @@ class Engine:
         # Host mirror of breaker states for the opt-in state-change
         # observers (rules/breaker_events.py); all-CLOSED on (re)build.
         # Epoch guards stale deferred fetches across rule reloads; seq
-        # orders concurrent/out-of-order _PendingFetch fills; validity
+        # orders concurrent/out-of-order _PendingChunk fills; validity
         # marks gaps where flushes ran unobserved (resync silently).
         self._breaker_state_host = np.zeros(
             self.degrade_dyn.state.shape[0], dtype=np.int32
@@ -772,7 +795,7 @@ class Engine:
         # nothing under _pending_lock takes another engine lock. RLock:
         # a fetch closure reading a lazy property of its own chunk must
         # not self-deadlock.
-        self._pending_fetches: "deque[_PendingFetch]" = deque()
+        self._pending_fetches: "deque[_PendingChunk]" = deque()
         self._pending_lock = threading.RLock()
         self._max_inflight = config.get_int(config.FLUSH_MAX_INFLIGHT, 2)
         # Depth-K flush pipeline (sentinel.tpu.host.pipeline.depth):
@@ -2997,7 +3020,7 @@ class Engine:
         records, cluster-token releases) until close()/reset() or the
         next traffic — fire-and-forget callers never read verdicts.
 
-        The depth bound counts _PendingFetch records, i.e. dispatched
+        The depth bound counts queued _PendingChunk records, i.e. dispatched
         chunks — one per flush except when a backlog exceeds
         ``max_batch`` and one flush splits into several chunks, in
         which case the trim settles the flush's own earliest chunks
@@ -3076,7 +3099,7 @@ class Engine:
         self._drain_pending()
 
     def _drain_pending(
-        self, upto: Optional[_PendingFetch] = None, keep: int = 0
+        self, upto: Optional[_PendingChunk] = None, keep: int = 0
     ) -> None:
         """Materialize queued async fetches oldest-first: through
         ``upto`` (inclusive) when given, else until at most ``keep``
@@ -3093,7 +3116,7 @@ class Engine:
         must not strand the queue)."""
         first_err: Optional[BaseException] = None
         while True:
-            recs: List[_PendingFetch] = []
+            recs: List[_PendingChunk] = []
             with self._pending_lock:
                 if upto is not None:
                     if not upto._done and upto in self._pending_fetches:
@@ -3195,29 +3218,34 @@ class Engine:
         also advance). ``out`` (entries, blocked_items)
         is filled IN PLACE chunk by chunk so the caller's finally still
         delivers completed chunks' block-log records and token releases
-        if a later chunk's kernel raises. With ``defer``, each chunk's
-        device→host fetch is queued as a _PendingFetch instead (out[1]
-        stays empty; post work rides with materialization)."""
+        if a later chunk's kernel raises. Each chunk's record settles
+        right after its dispatch, here under the flush lock — or, with
+        ``defer``, it is queued for ``_drain_pending`` (out[1] stays
+        empty; post work rides with materialization)."""
         out = out if out is not None else ([], [])
         n_chunks = [0]
 
         def _chunk(entries_c, exits_c, bulk_c, bulk_x_c, findex, dindex,
                    pindex, auth_rules) -> None:
-            res = self._run_chunk(
+            rec = self._run_chunk(
                 entries_c, exits_c, bulk_c, bulk_x_c, findex, dindex, pindex,
                 auth_rules, defer=defer,
             )
+            if not defer:
+                out[1].extend(rec.settle())
+            elif rec._refs is None:
+                # Served from policy (nothing to fetch): settle and
+                # post now instead of queueing.
+                rec.materialize()
+            else:
+                for op in rec._entries:
+                    op._pending = rec
+                for g in rec._bulk:
+                    g._pending = rec
+                with self._pending_lock:
+                    self._pending_fetches.append(rec)
             out[0].extend(entries_c)
             n_chunks[0] += 1
-            if defer:
-                # A faulted chunk fills from policy inside _run_chunk
-                # (its post work already ran) and returns None — only
-                # real dispatches enqueue a pending fetch.
-                if isinstance(res, _PendingFetch):
-                    with self._pending_lock:
-                        self._pending_fetches.append(res)
-            else:
-                out[1].extend(res)
         tele = self.telemetry
         swap = tele.phase("sentinel.flush.swap", flush=self._flush_seq + 1)
         with swap, self._lock:
@@ -3409,12 +3437,12 @@ class Engine:
         pindex: ParamIndex,
         auth_rules: Dict[str, AuthorityRule],
         defer: bool = False,
-    ) -> object:
-        """Encode one chunk, run the kernel, fill verdicts; returns the
-        chunk's blocked-verdict block-log items (file IO happens outside
-        the flush lock, in _post_flush) — or, with ``defer``, a
-        _PendingFetch that performs the fetch + fill on
-        materialization. Runs under
+    ) -> _PendingChunk:
+        """Encode one chunk and dispatch the kernel; returns the chunk's
+        record, whose ``settle`` fetches and fills the verdicts.
+        ``defer`` marks a record that will be queued rather than settled
+        before the next dispatch: the device state riding its fetch is
+        then copied. Runs under
         the flush lock only — the indexes are the snapshot taken when
         the pending buffers were swapped; _flush_locked re-resolved any
         op whose submit-time tables were superseded by a reload.
@@ -3425,11 +3453,17 @@ class Engine:
         fo = self.failover
         if fo.armed and not fo.healthy:
             # An earlier chunk of this same flush tripped failover:
-            # don't touch the device again — fill from policy (custom
-            # slot checks have not run for this chunk yet).
-            return self._degraded_chunk(fo, entries, exits, bulk,
-                                        bulk_exits, defer,
-                                        run_custom_slots=True)
+            # don't touch the device again — the record (no flush seq)
+            # settles from policy. Capture sees the chunk here (seq -1).
+            cap = self.capture
+            cap_tok = (
+                cap.note_chunk(entries, exits, bulk, bulk_exits,
+                               self.clock.now_ms(), -1)
+                if cap is not None
+                else None
+            )
+            return _PendingChunk(self, entries, exits, bulk, bulk_exits,
+                                 cap_tok=cap_tok)
         # ---- custom processor slots (SPI-assembled chain head) ----
         # A registered slot's veto blocks the entry before every device
         # stage — accounted like a first-slot BlockException (the block
@@ -3605,15 +3639,13 @@ class Engine:
                 raise
             # The dispatch faulted: the device states may or may not
             # have been consumed (donation) — either way the chain is
-            # unrecoverable without a restore. Quarantine + fill this
-            # chunk from policy; staging drops to GC (the computation
-            # may still read it zero-copy if it did start).
+            # unrecoverable without a restore. The record has no
+            # results, so it settles from policy; staging drops to GC
+            # (the computation may still read it zero-copy if it did
+            # start).
             fo.trip("dispatch", exc, seq)
-            return self._degraded_chunk(fo, entries, exits, bulk,
-                                        bulk_exits, defer, span=span,
-                                        run_custom_slots=False,
-                                        quarantined=True,
-                                        cap_tok=cap_tok)
+            return _PendingChunk(self, entries, exits, bulk, bulk_exits,
+                                 span=span, seq=seq, cap_tok=cap_tok)
         (
             self.stats, self.flow_dyn, self.degrade_dyn, self.param_dyn,
             new_skstate, result,
@@ -3645,6 +3677,14 @@ class Engine:
             # cost into its memo at the next tick.
             span.param_bucket, span.param_path = param_pick
 
+        # Device state that rides this chunk's fetch: a queued chunk
+        # must copy it, because the next flush donates these buffers
+        # into its kernel, which deletes them before a deferred
+        # device_get runs ("Array has been deleted"). Depth 0 settles
+        # before the next dispatch and keeps zero-copy references.
+        def snap(tree):
+            return jax.tree_util.tree_map(jnp.copy, tree) if defer else tree
+
         # Opt-in breaker state-change observers: capture THIS chunk's
         # post-flush state (tagged with epoch+seq — dispatches are
         # serialized under _flush_lock, so seq follows dispatch order)
@@ -3669,21 +3709,10 @@ class Engine:
             or cap is not None
         ):
             self._breaker_seq += 1
-            # Deferred fetches must NOT hold the live dyn-state buffer:
-            # the next flush donates degrade_dyn into its kernel, which
-            # deletes the array before the deferred device_get runs
-            # ("Array has been deleted"). A copy breaks the aliasing;
-            # the sync path fetches before the next dispatch, so it can
-            # keep the zero-copy reference.
-            state_snap = (
-                jnp.copy(self.degrade_dyn.state)
-                if defer
-                else self.degrade_dyn.state
-            )
-            breaker_snap = (self._breaker_epoch, self._breaker_seq,
-                            state_snap)
+            breaker_tag = (self._breaker_epoch, self._breaker_seq)
+            breaker = snap(self.degrade_dyn.state)
         else:
-            breaker_snap = None
+            breaker_tag = breaker = None
             with self._breaker_mirror_lock:
                 self._breaker_mirror_valid = False
                 # Also fence out older in-flight deferred fills: a fill
@@ -3699,11 +3728,9 @@ class Engine:
         # Speculative shaping-mirror reconcile: the settled pacer /
         # warm-up dyn columns ride the SAME coalesced fetch whenever
         # the tier serves shaped resources — the host mirror re-anchors
-        # to device truth at every drain for free. Deferred chunks copy
-        # (the next flush's shaping kernel donates flow_dyn, deleting
-        # the arrays before a deferred fetch runs — the breaker_snap
-        # hazard); the sync path fetches before the next dispatch.
+        # to device truth at every drain for free.
         spec_tier = self.speculative
+        shaping = None
         if (
             spec_tier.enabled
             and spec_tier.mirror.shaping_enabled
@@ -3711,128 +3738,62 @@ class Engine:
             and self.mesh is None
         ):
             fd = self.flow_dyn
-            if defer:
-                shaping_snap = (
-                    jnp.copy(fd.latest_passed_time),
-                    jnp.copy(fd.stored_tokens),
-                    jnp.copy(fd.last_filled_time),
-                )
-            else:
-                shaping_snap = (
-                    fd.latest_passed_time, fd.stored_tokens,
-                    fd.last_filled_time,
-                )
-        else:
-            shaping_snap = None
+            shaping = snap((fd.latest_passed_time, fd.stored_tokens,
+                            fd.last_filled_time))
 
-        # Sketch candidate table: rides the same coalesced fetch. A
-        # deferred chunk must copy — the next flush donates the sketch
-        # state into its kernel, deleting the arrays before a deferred
-        # fetch runs (the breaker_snap hazard).
+        # Sketch candidate table: rides the same coalesced fetch.
+        sketch = None
         if new_skstate is not None:
-            if defer:
-                sk_snap = (
-                    jnp.copy(new_skstate.cand_ids),
-                    jnp.copy(new_skstate.cand_cnt),
-                )
-            else:
-                sk_snap = (new_skstate.cand_ids, new_skstate.cand_cnt)
-        else:
-            sk_snap = None
+            sketch = snap((new_skstate.cand_ids, new_skstate.cand_cnt))
 
-        has_blk = result.blk_rows is not None
         # Admission-trace flush linkage: the deciding flush-span seq
         # (TelemetryBus ids) — -1 when the flight recorder is off.
         flush_seq = span.flush_id if span is not None else -1
 
         # Host checkpoint (failover): every N flushes the fresh device
         # states ride the SAME coalesced result fetch to the host as
-        # the last-good restore point — no extra round-trip. A deferred
-        # chunk's states must be copied: the next flush donates them
-        # into its kernel, which deletes the arrays before the deferred
-        # fetch runs (same hazard as breaker_snap above).
-        ckpt_meta = None
+        # the last-good restore point — no extra round-trip.
+        ckpt_meta = ckpt = None
         if fo.armed and fo.checkpoint_due(seq):
             # The sketch tier joins the checkpoint (PR 15): its keys are
             # stable CRC ids, so the table restores position-independent
             # — an engine trip (or a hot-restarted process loading the
             # durable spill) keeps heavy-hitter protection instead of
             # silently resetting it.
-            states = (self.stats, self.flow_dyn, self.degrade_dyn,
-                      self.param_dyn, new_skstate)
-            if defer:
-                states = jax.tree_util.tree_map(jnp.copy, states)
+            ckpt = snap((self.stats, self.flow_dyn, self.degrade_dyn,
+                         self.param_dyn, new_skstate))
             ckpt_meta = fo.begin_checkpoint(
                 seq, now_host, findex, dindex, pindex
             )
 
-        def _fill(got):
+        def _fill(got: _ChunkResults) -> List[tuple]:
             if ckpt_meta is not None:
-                fo.store_checkpoint(ckpt_meta, got[-1])
-                got = got[:-1]
+                fo.store_checkpoint(ckpt_meta, got.ckpt)
             res = self._fill_results(
                 got, entries, exits, bulk, bulk_exits, findex, dindex,
-                auth_rules, k, kd, breaker_snap=breaker_snap,
-                blk_topk=has_blk, flush_seq=flush_seq,
-                shaping_snap=shaping_snap is not None,
-                sketch_snap=sk_snap is not None,
+                auth_rules, k, kd, breaker_tag=breaker_tag,
+                flush_seq=flush_seq,
             )
             if cap_tok is not None:
                 cap.note_verdicts(cap_tok, entries, bulk)
             return res
 
-        refs = self._result_refs(result, breaker_snap, shaping_snap, sk_snap)
-        if ckpt_meta is not None:
-            refs = refs + (states,)
-        if defer:
-            if span is not None:
-                tele.dispatch_done(span)
-            rec = _PendingFetch(
-                self, entries, refs, _fill, staging=staging, span=span,
-                bulk=bulk, seq=seq, exits=exits, bulk_exits=bulk_exits,
-                cap_tok=cap_tok,
-            )
-            for op in entries:
-                op._pending = rec
-            for g in bulk:
-                g._pending = rec
-            return rec
-        t_fetch0 = time.perf_counter()
-        faulted = False
-        try:
-            got = self._fetch_refs(refs, (seq,), span)
-            with phase("sentinel.fill", span, "fill_ms", flush=seq,
-                       groups=len(bulk)):
-                res = _fill(got)
-        except BaseException as exc:
-            if not fo.armed:
-                raise
-            # Fetch fault/timeout on the synchronous path: the
-            # verdicts are lost — quarantine the older in-flight
-            # queue and fill this chunk from policy. Callers never
-            # see the raw device exception.
-            faulted = True
-            fo.trip("fetch", exc, seq)
-            res = self._degraded_chunk(
-                fo, entries, exits, bulk, bulk_exits, defer,
-                span=span, run_custom_slots=False, quarantined=True,
-                cap_tok=cap_tok,
-            )
-        # Results fetched → the computation has consumed its (possibly
-        # zero-copy) inputs; staging is reusable. ONLY on success: a
-        # failed/interrupted fetch proves nothing about the dispatched
-        # computation, so its staging is dropped to GC, never pooled.
-        if not faulted:
-            if self._arena is not None:
-                self._arena.give_all(staging)
-            if span is not None:
-                tele.settle(span, t_fetch0, time.perf_counter())
-            if self.ingest.armed:
-                # Settle-latency signal for the ingest deadline valve.
-                self.ingest.note_settle_ms(
-                    (time.perf_counter() - t_fetch0) * 1e3
-                )
-        return res
+        refs = _ChunkResults(
+            result.admitted, result.reason, result.slot_ok, result.wait_ms,
+            result.sys_type, result.dslot_ok,
+            blk=(
+                None if result.blk_rows is None
+                else (result.blk_rows, result.blk_weight)
+            ),
+            breaker=breaker, shaping=shaping, sketch=sketch, ckpt=ckpt,
+        )
+        if defer and span is not None:
+            tele.dispatch_done(span)
+        return _PendingChunk(
+            self, entries, exits, bulk, bulk_exits, refs=refs, fill=_fill,
+            staging=staging, span=span, seq=seq, cap_tok=cap_tok,
+            deferred=defer,
+        )
 
     def _encode_staging(
         self,
@@ -4023,44 +3984,6 @@ class Engine:
         runs = (n_runs, e_runs.shape[0], nx_runs, x_runs.shape[0])
         return batch, staging, k, kd, now_host, runs
 
-    def _degraded_chunk(
-        self, fo, entries, exits, bulk, bulk_exits, defer, span=None,
-        run_custom_slots=True, quarantined=False, cap_tok=None,
-    ) -> Optional[List[tuple]]:
-        """Fill one chunk's verdicts from the host fallback (device
-        fault mid-flush, or the engine degraded before this chunk
-        dispatched). Synchronous chunks return their block-log items
-        for the caller's normal _post_flush; deferred chunks have no
-        materialization to ride, so post work runs here and None is
-        returned (nothing to enqueue). ``run_custom_slots=False`` when
-        the chunk already ran the custom slot checks before faulting;
-        ``quarantined=True`` when the chunk's own device results were
-        LOST to the fault (counted — chunks merely served while
-        already degraded are not)."""
-        if quarantined:
-            fo.note_quarantined()
-        if span is not None:
-            span.quarantined = True
-            self.telemetry.settle(
-                span, time.perf_counter(), time.perf_counter()
-            )
-        cap = self.capture
-        if cap is not None and cap_tok is None:
-            # Degraded before dispatch: the chunk never passed the
-            # note_chunk hook in _run_chunk — capture it here (seq -1:
-            # no flush sequence number was ever assigned).
-            cap_tok = cap.note_chunk(
-                entries, exits, bulk, bulk_exits, self.clock.now_ms(), -1
-            )
-        items = fo.fill_degraded(entries, exits, bulk, bulk_exits,
-                                 run_custom_slots=run_custom_slots)
-        if cap is not None:
-            cap.note_verdicts(cap_tok, entries, bulk, degraded=True)
-        if defer:
-            self._post_flush((entries, items))
-            return None
-        return items
-
     def _reset_breaker_mirror(self) -> None:
         """Fresh all-CLOSED mirror + a new epoch: deferred fetches
         captured before a rule reload/reset must never diff (or fire)
@@ -4105,35 +4028,6 @@ class Engine:
                     if (r := dindex.rule_of_gid(int(gid))) is not None
                 ]
                 cap.note_breaker_open(opened)
-
-    @staticmethod
-    def _result_refs(result, breaker_snap, shaping_snap=None, sk_snap=None) -> tuple:
-        """The device arrays one chunk's verdict fill consumes — kept
-        as a tuple so a drain can batch MANY chunks' refs into one
-        coalesced ``jax.device_get`` (each separate fetch costs a full
-        device round-trip). The breaker state rides
-        the same fetch when observers are registered; the shaping dyn
-        columns ride it when the speculative shaping mirror is on; the
-        sketch candidate table rides it when the sketch tier is armed."""
-        refs = (
-            result.admitted,
-            result.reason,
-            result.slot_ok,
-            result.wait_ms,
-            result.sys_type,
-            result.dslot_ok,
-        )
-        if result.blk_rows is not None:
-            # Telemetry blocked-resource top-K rides the same coalesced
-            # fetch — no extra round-trip for "what is throttled now".
-            refs = refs + (result.blk_rows, result.blk_weight)
-        if breaker_snap is not None:
-            refs = refs + (breaker_snap[2],)
-        if shaping_snap is not None:
-            refs = refs + shaping_snap
-        if sk_snap is not None:
-            refs = refs + sk_snap
-        return refs
 
     def _fold_blocked_sketch(self, rows, weights) -> None:
         """Resolve one fetched device top-K (cluster rows → resource
@@ -4188,47 +4082,36 @@ class Engine:
         auth_rules: Dict[str, AuthorityRule],
         k: int,
         kd: int,
-        breaker_snap=None,
-        blk_topk: bool = False,
+        breaker_tag: Optional[Tuple[int, int]] = None,
         flush_seq: int = -1,
-        shaping_snap: bool = False,
-        sketch_snap: bool = False,
     ) -> List[tuple]:
         """Verdict fill for one dispatched chunk from its ALREADY
-        FETCHED result tuple (``got`` = the host values of
-        :meth:`_result_refs`); returns the chunk's blocked-verdict
-        block-log items. Runs either synchronously at the end of
-        _run_chunk or deferred from a _PendingFetch materialization."""
-        admitted, reason, slot_ok, wait_ms, sys_type, dslot_ok = got[:6]
-        nxt = 6
-        if blk_topk:
-            self._fold_blocked_sketch(got[6], got[7])
-            nxt = 8
-        if breaker_snap is not None:
+        FETCHED results; returns the chunk's blocked-verdict block-log
+        items. ``breaker_tag`` is the (epoch, seq) of ``got.breaker``.
+        Runs from the chunk record's settle (``_PendingChunk.settle``)."""
+        admitted, reason, slot_ok = got.admitted, got.reason, got.slot_ok
+        wait_ms, sys_type, dslot_ok = got.wait_ms, got.sys_type, got.dslot_ok
+        if got.blk is not None:
+            self._fold_blocked_sketch(*got.blk)
+        if breaker_tag is not None:
             self._apply_breaker_snapshot(
-                breaker_snap[0], breaker_snap[1],
-                np.asarray(got[nxt], dtype=np.int32).reshape(-1), dindex,
+                breaker_tag[0], breaker_tag[1],
+                np.asarray(got.breaker, dtype=np.int32).reshape(-1), dindex,
             )
-            nxt += 1
-        if shaping_snap:
+        if got.shaping is not None:
             # Settled shaping dyn columns: re-anchor the host pacer /
             # warm-up mirrors to device truth (the per-drain
             # reconciliation contract of the shaping fast tier).
             self.speculative.reconcile_shaping(
-                findex,
-                np.asarray(got[nxt]), np.asarray(got[nxt + 1]),
-                np.asarray(got[nxt + 2]),
+                findex, *(np.asarray(c) for c in got.shaping)
             )
-            nxt += 3
-        if sketch_snap:
+        if got.sketch is not None:
             # Settled sketch candidate table: the promotion/demotion
             # controller evaluates at every drain (runtime/sketch.py).
             self.sketch.on_drain(
-                np.asarray(got[nxt], dtype=np.int32),
-                np.asarray(got[nxt + 1], dtype=np.int32),
+                *(np.asarray(c, dtype=np.int32) for c in got.sketch),
                 self.clock.now_ms(),
             )
-            nxt += 2
         # One verdict-materialization timestamp for every admission in
         # the chunk (they all settle together; per-op clocks would add
         # a syscall per row for no attribution gain).
@@ -4368,7 +4251,7 @@ class Engine:
                 g.trace = None
             off_b += g.n
 
-        if not blk_topk and self._blk_topk_k > 0:
+        if got.blk is None and self._blk_topk_k > 0:
             # Kernel paths without the device fold (the sharded mesh
             # flush) still feed the blocked top-K: recount blocked
             # weight host-side from the verdicts just filled — exact,

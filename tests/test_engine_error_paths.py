@@ -9,6 +9,12 @@ failover DISARMED, device errors must keep their original semantics:
   records still materialize;
 * dirty shutdown: a worker thread that outlives its close-join is
   reported (``closed_dirty``) instead of silently leaked.
+
+Alongside them, the settle-equivalence cases pin one dispatched chunk's
+settle — fetch, fill, fault handling, arena give-back, span settle —
+as the same observable result at depth 0, depth 2 and
+``flush_async`` + ``drain``: healthy, with a fetch fault under armed
+failover, and with a fill error under disarmed failover.
 """
 
 import threading
@@ -18,6 +24,7 @@ import pytest
 
 import sentinel_tpu as st
 from sentinel_tpu.testing.faults import FaultInjector, InjectedFault
+from sentinel_tpu.utils.config import config
 
 
 def _mk_engine(manual_clock, depth=0, max_batch=None):
@@ -114,6 +121,136 @@ class TestDrainPerRecordFallback:
         for op in ops_a:
             with pytest.raises(InjectedFault):
                 _ = op.verdict
+
+
+def _settle_run(manual_clock, monkeypatch, mode, fault):
+    """One chunk of 8 singles and a bulk group of 8 on a count-5 rule,
+    settled through ``mode``; returns what a caller can observe."""
+    from sentinel_tpu.runtime.engine import Engine
+
+    armed = fault == "fetch_armed"
+    config.set(config.FAILOVER_ENABLED, "true" if armed else "false")
+    config.set(config.FAILOVER_CHECKPOINT_EVERY, "1")
+    eng = Engine(clock=manual_clock)
+    eng.pipeline_depth = 2 if mode == "depth2" else 0
+    eng.set_flow_rules([st.FlowRule("r", count=5)])
+    logged = []
+    monkeypatch.setattr(eng.block_log, "log_batch", logged.extend)
+    given = []
+    give_all = eng._arena.give_all
+    monkeypatch.setattr(
+        eng._arena, "give_all", lambda st_: (given.append(len(st_)), give_all(st_))
+    )
+    inj = FaultInjector().install(eng)
+    manual_clock.set_ms(1000)
+    err = RuntimeError("fill failed")
+    if fault == "fetch_armed":
+        inj.fail_fetch(eng.flush_seq + 1)
+    elif fault == "fill_unarmed":
+        def broken_fill(*a, **kw):
+            raise err
+
+        monkeypatch.setattr(eng, "_fill_results", broken_fill)
+    ops = [eng.submit_entry("r", ts=1000) for _ in range(8)]
+    g = eng.submit_bulk("r", 8, ts=1000)
+    drains0 = eng.telemetry.hist_drain.count
+    raised = {}
+    for step in ("flush", "drain"):
+        try:
+            if step == "drain":
+                eng.drain()
+            elif mode == "async":
+                eng.flush_async()
+            else:
+                eng.flush()
+        except RuntimeError as exc:
+            raised[step] = exc
+    (span,) = eng.telemetry.spans()[-1:]
+    obs = {
+        "raised": {k: v is err for k, v in raised.items()},
+        "logged": list(logged),
+        "given": list(given),
+        "degraded_state": eng.failover.degraded,
+        "span": (span.deferred, span.settled, span.quarantined),
+        "drain_samples": eng.telemetry.hist_drain.count - drains0,
+        "fetch_fill_ms": (span.fetch_ms, span.fill_ms),
+    }
+    if fault == "fill_unarmed" and mode != "depth0":
+        # Every reader re-raises the stored error, repeatably.
+        for _ in range(2):
+            for read in (lambda: ops[0].verdict, lambda: g.admitted_count):
+                with pytest.raises(RuntimeError) as ei:
+                    read()
+                assert ei.value is err
+        obs["verdicts"] = obs["bulk"] = None
+    else:
+        obs["verdicts"] = [
+            None if v is None else (v.admitted, v.reason, v.wait_ms, v.degraded)
+            for v in (op.verdict for op in ops)
+        ]
+        obs["bulk"] = None if g.admitted is None else (
+            g.admitted.tolist(), g.reason.tolist(), g.wait_ms.tolist()
+        )
+    return obs
+
+
+class TestSettleEquivalence:
+    @pytest.fixture(autouse=True)
+    def _config_sandbox(self):
+        with config._lock:
+            saved = dict(config._runtime)
+        yield
+        with config._lock:
+            config._runtime.clear()
+            config._runtime.update(saved)
+
+    @pytest.mark.parametrize("fault", ["healthy", "fetch_armed", "fill_unarmed"])
+    @pytest.mark.parametrize("mode", ["depth0", "depth2", "async"])
+    def test_settle_paths_agree(self, manual_clock, monkeypatch, mode, fault):
+        """The same chunk settles to the same verdicts, reasons and
+        block-log items at every depth; a fault's provenance, its
+        error's route to the caller, the arena give-back and depth 0's
+        own telemetry are pinned per mode."""
+        ref = _settle_run(manual_clock, monkeypatch, "depth0", fault)
+        monkeypatch.undo()
+        obs = _settle_run(manual_clock, monkeypatch, mode, fault)
+        deferred = mode != "depth0"
+        if fault != "fill_unarmed" or not deferred:
+            assert obs["verdicts"] == ref["verdicts"]
+            assert obs["bulk"] == ref["bulk"]
+        assert obs["logged"] == ref["logged"]
+        assert obs["degraded_state"] == (fault == "fetch_armed")
+        assert obs["span"][0] == deferred
+        if fault == "healthy":
+            adm = [v[0] for v in obs["verdicts"]] + obs["bulk"][0]
+            assert sum(adm) == 5 and not any(v[3] for v in obs["verdicts"])
+            assert len(obs["logged"]) > 0
+            assert obs["raised"] == {}
+            # Staging goes back to the arena after the successful fetch.
+            assert len(obs["given"]) == 1 and obs["given"][0] > 0
+            assert obs["span"] == (deferred, True, False)
+        elif fault == "fetch_armed":
+            # Degraded provenance on every op; the device error never
+            # reaches a caller; the faulted chunk's staging is dropped.
+            assert all(v is not None and v[3] for v in obs["verdicts"])
+            assert obs["raised"] == {}
+            assert obs["given"] == []
+            assert obs["span"] == (deferred, True, True)
+        else:
+            # At depth 0 flush() raises and the chunk's ops stay
+            # unfilled; deferred, the drain raises the stored error.
+            assert obs["raised"] == {"drain" if deferred else "flush": True}
+            assert obs["logged"] == []
+            assert obs["given"] == []
+            assert obs["span"][1:] == (False, False)
+            if not deferred:
+                assert obs["verdicts"] == [None] * 8 and obs["bulk"] is None
+        if not deferred:
+            # Depth 0 fills the span's fetch and fill phases and takes
+            # no drain-histogram sample.
+            assert obs["drain_samples"] == 0
+            if fault == "healthy":
+                assert min(obs["fetch_fill_ms"]) > 0
 
 
 class TestDirtyShutdown:
