@@ -52,7 +52,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass
-from typing import Dict, List, NamedTuple, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -218,6 +218,59 @@ class AdmissionRecord:
         }
 
 
+def _make_record(
+    tag: TraceTag,
+    resource: str,
+    origin: str,
+    context_name: str,
+    admitted: bool,
+    reason: int,
+    flush_seq: int,
+    latency_ms: float,
+    degraded: bool,
+    provenance: str,
+    settled_match: Optional[bool] = None,
+) -> AdmissionRecord:
+    """One record under the tag's trace (trace-rooted without a parent);
+    an empty ``provenance`` reads "degraded" or "device"."""
+    parent = tag.parent
+    return AdmissionRecord(
+        trace_id=parent.trace_id if parent is not None else new_trace_id(),
+        span_id=new_span_id(),
+        parent_span_id=parent.span_id if parent is not None else "",
+        resource=resource,
+        origin=origin,
+        context_name=context_name,
+        admitted=admitted,
+        reason=reason,
+        reason_name="" if admitted else E.exc_name_for_code(reason),
+        flush_seq=int(flush_seq),
+        t0=tag.t0,
+        latency_ms=latency_ms,
+        head_sampled=tag.sampled,
+        degraded=degraded,
+        provenance=provenance or ("degraded" if degraded else "device"),
+        settled_match=settled_match,
+    )
+
+
+def _first_rows(
+    mask: np.ndarray,
+    starts: np.ndarray,
+    stops: np.ndarray,
+    want: np.ndarray,
+    cap: int,
+) -> Tuple[np.ndarray, np.ndarray]:
+    """(group, row) of the first ``cap`` set rows of ``mask`` in each
+    group's range ``[starts, stops)`` whose ``want`` is set; groups in
+    order, rows ascending."""
+    hits = np.flatnonzero(mask)
+    lo = np.searchsorted(hits, starts)
+    n = np.minimum(np.searchsorted(hits, stops) - lo, cap) * want
+    take = np.repeat(lo - (np.cumsum(n) - n), n) + np.arange(int(n.sum()))
+    return np.repeat(np.arange(starts.size), n), hits[take]
+
+
 class AdmissionTracer:
     """Engine-scoped sampled admission-trace ring (one per
     :class:`~sentinel_tpu.runtime.engine.Engine`, like the
@@ -314,26 +367,10 @@ class AdmissionTracer:
         if not (tag.sampled or (not admitted and self.sample_blocked)):
             self._skipped += 1
             return None
-        if not provenance:
-            provenance = "degraded" if degraded else "device"
-        parent = tag.parent
-        rec = AdmissionRecord(
-            trace_id=parent.trace_id if parent is not None else new_trace_id(),
-            span_id=new_span_id(),
-            parent_span_id=parent.span_id if parent is not None else "",
-            resource=resource,
-            origin=origin,
-            context_name=context_name,
-            admitted=bool(admitted),
-            reason=int(reason),
-            reason_name="" if admitted else E.exc_name_for_code(reason),
-            flush_seq=int(flush_seq),
-            t0=tag.t0,
-            latency_ms=max(0.0, (end_pc - tag.t0) * 1e3),
-            head_sampled=tag.sampled,
-            degraded=degraded,
-            provenance=provenance,
-            settled_match=settled_match,
+        rec = _make_record(
+            tag, resource, origin, context_name, bool(admitted), int(reason),
+            flush_seq, max(0.0, (end_pc - tag.t0) * 1e3), degraded,
+            provenance, settled_match,
         )
         self.hist_latency.record(rec.latency_ms)
         bucket = self.hist_latency.bucket_of(rec.latency_ms)
@@ -359,36 +396,91 @@ class AdmissionTracer:
         end_pc: float,
         degraded: bool = False,
         provenance: str = "",
-        settled_match: Optional[bool] = None,
     ) -> None:
-        """Bounded per-row records for one bulk group: up to
-        ``bulk_cap`` blocked rows (always-blocked mode) plus, when the
-        group's head tag sampled, up to ``bulk_cap`` admitted rows —
-        never a full walk of the group. Bulk rows have no per-request
-        inbound identity, so each record is trace-rooted unless the
-        SUBMITTING call carried one (then all rows share its trace)."""
+        """Bounded per-row records for one bulk group (see
+        :meth:`record_bulk_groups`)."""
+        self.record_bulk_groups(
+            [(0, np.asarray(admitted).size, tag, resource, origin,
+              context_name, degraded, provenance)],
+            admitted, reasons, flush_seq, end_pc,
+        )
+
+    def record_bulk_groups(
+        self,
+        groups: Sequence[tuple],
+        admitted,
+        reasons,
+        flush_seq: int,
+        end_pc: float,
+    ) -> None:
+        """Bounded per-row records for a chunk's bulk groups, from one
+        pass over its verdict columns. ``groups`` holds one ``(start,
+        stop, tag, resource, origin, context_name, degraded,
+        provenance)`` per group, in order, its rows ``[start, stop)`` of
+        ``admitted`` / ``reasons``. Each group records up to
+        ``bulk_cap`` blocked rows (always-blocked mode, or a sampled
+        head tag), then, when its head tag sampled, up to ``bulk_cap``
+        admitted rows — never a full walk of a group. Bulk rows have no
+        per-request inbound identity, so each record is trace-rooted
+        unless the SUBMITTING call carried one (then all rows share its
+        trace).
+
+        Only the records the ring keeps are built. The counters, the
+        latency histogram and the exemplars count every record, as one
+        :meth:`record_admission` per row would: a chunk of thousands of
+        blocked groups records more rows than the ring holds."""
         cap = self.bulk_cap
-        if cap <= 0:
+        if cap <= 0 or not groups:
             return
-        # Vectorized row selection — a Python walk of a 100k-row group
-        # per flush would be exactly the per-row interpreter work the
-        # columnar bulk path exists to avoid.
         adm = np.asarray(admitted)
-        rows: List[int] = []
-        if self.sample_blocked or tag.sampled:
-            rows.extend(np.flatnonzero(~adm)[:cap].tolist())
-        if tag.sampled:
-            rows.extend(np.flatnonzero(adm)[:cap].tolist())
-        # record_admission's own gate re-applies (a blocked row rides
-        # the always-blocked mode; an admitted row needs tag.sampled),
-        # so the per-row record keeps honest head_sampled attribution.
-        for i in rows:
-            self.record_admission(
-                tag, resource, origin, context_name,
-                bool(adm[i]), int(reasons[i]), flush_seq, end_pc,
-                degraded=degraded, provenance=provenance,
-                settled_match=settled_match,
-            )
+        starts = np.array([gr[0] for gr in groups], dtype=np.intp)
+        stops = np.array([gr[1] for gr in groups], dtype=np.intp)
+        sampled = np.array([gr[2].sampled for gr in groups], dtype=bool)
+        gi_b, rows_b = _first_rows(
+            ~adm, starts, stops, sampled | self.sample_blocked, cap
+        )
+        gi_a, rows_a = _first_rows(adm, starts, stops, sampled, cap)
+        gi = np.concatenate((gi_b, gi_a))
+        n = gi.size
+        if not n:
+            return
+        # Each group's blocked rows, then its admitted rows (stable).
+        order = np.lexsort((np.arange(n) >= gi_b.size, gi))
+        gi = gi[order]
+        rows = np.concatenate((rows_b, rows_a))[order]
+        per_group = np.bincount(gi, minlength=len(groups))
+        js = np.flatnonzero(per_group).tolist()
+        lat = {j: max(0.0, (end_pc - groups[j][2].t0) * 1e3) for j in js}
+        buckets = self.hist_latency.record_runs(
+            list(lat.values()), per_group[js].tolist()
+        )
+        first_kept = max(0, n - self.ring_size)
+        kept = []
+        for j, i in zip(gi[first_kept:].tolist(), rows[first_kept:].tolist()):
+            _, _, tag, resource, origin, ctx, degraded, prov = groups[j]
+            kept.append(_make_record(
+                tag, resource, origin, ctx, bool(adm[i]), int(reasons[i]),
+                flush_seq, lat[j], degraded, prov,
+            ))
+        # Per bucket, its last record (groups in order, so a later
+        # group's last record replaces an earlier one's).
+        last = dict(zip(buckets, (np.cumsum(per_group[js]) - 1).tolist()))
+        exemplars = {}
+        for b, pos in last.items():
+            j = int(gi[pos])
+            if pos >= first_kept:
+                tid = kept[pos - first_kept].trace_id
+            else:
+                parent = groups[j][2].parent
+                tid = parent.trace_id if parent is not None else new_trace_id()
+            exemplars[b] = (tid, lat[j])
+        n_head = int(sampled[gi].sum())
+        with self._lock:
+            self._records.extend(kept)
+            self.counters["recorded"] += n
+            self.counters["head_sampled"] += n_head
+            self.counters["blocked_sampled"] += n - n_head
+            self._exemplars.update(exemplars)
 
     # ------------------------------------------------------------------
     # readers

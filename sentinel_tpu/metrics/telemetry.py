@@ -107,6 +107,10 @@ class FlushSpan:
     shaping_rounds: int = 0  # pacer mode: -1 closed form, 0 scan, >0 rounds
     param_items: int = 0  # hot-param items encoded
     groups: int = 0  # bulk entry groups whose verdicts the fill fans out
+    # Of them, groups left on the speculative reconcile path, and the
+    # block-log items the fill produced (``sentinel.fill`` args).
+    spec_groups: int = 0
+    block_items: int = 0
     # Statistics accounting runs (nodes.apply_run_updates): spans of
     # entries / exits sharing node rows and ts, and their padded table
     # lengths — the kernel merges 4 records per table slot.
@@ -170,6 +174,8 @@ class FlushSpan:
             "shaping_rounds": self.shaping_rounds,
             "param_items": self.param_items,
             "groups": self.groups,
+            "spec_groups": self.spec_groups,
+            "block_items": self.block_items,
             "runs": self.runs,
             "runs_padded": self.runs_padded,
             "x_runs": self.x_runs,

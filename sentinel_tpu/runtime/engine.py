@@ -179,7 +179,8 @@ class _PendingChunk:
         self._bulk = bulk
         self._bulk_exits = bulk_exits
         self._refs = refs  # device arrays awaiting their host fetch
-        self._fill = fill  # (fetched _ChunkResults) -> blocked_items
+        # (fetched _ChunkResults) -> (blocked_items, spec_groups)
+        self._fill = fill
         # Arena staging buffers held until the fetch completes (the
         # dispatched computation may read them zero-copy until then).
         self._staging = staging or []
@@ -233,8 +234,9 @@ class _PendingChunk:
                 if self._deferred:
                     eng._note_drain_ms((time.perf_counter() - t0) * 1e3)
             with tele.phase("sentinel.fill", span, "fill_ms",
-                            flush=self._seq, groups=len(chunk[2])):
-                items = fill(got)
+                            flush=self._seq, groups=len(chunk[2])) as ph:
+                items, spec_groups = fill(got)
+                ph.set(spec_groups=spec_groups, block_items=len(items))
         except BaseException as exc:
             if fo.armed:
                 fo.trip("fetch", exc, self._seq)
@@ -244,6 +246,8 @@ class _PendingChunk:
         if staging and eng._arena is not None:
             eng._arena.give_all(staging)
         if span is not None:
+            span.spec_groups = spec_groups
+            span.block_items = len(items)
             # For a coalesced drain the fetch cost is in the drain
             # histogram: the span's settle window holds this record's
             # own fill (and its own fetch on the fallback path).
@@ -448,7 +452,7 @@ class BulkOp:
     _wait_ms: Optional[np.ndarray] = field(default=None, repr=False)
     _pending: Optional[_PendingChunk] = field(default=None, repr=False, compare=False)
     # Group-level admission-trace stamp (bounded per-row records land
-    # at verdict fill — see AdmissionTracer.record_bulk).
+    # at verdict fill — see AdmissionTracer.record_bulk_groups).
     trace: Optional[object] = field(default=None, repr=False, compare=False)
     # Speculative-tier verdict copy (runtime/speculative.py): non-None
     # marks the group as speculatively decided — the settled device
@@ -595,6 +599,62 @@ def _weighted_rt(gx: "_BulkExitOp") -> int:
     if total <= 0:
         return 0
     return int((gx.rt.astype(np.int64) * gx.count).sum() // total)
+
+
+def _tally_blocked(
+    admitted: np.ndarray,
+    reason: np.ndarray,
+    acquire: np.ndarray,
+    sizes: np.ndarray,
+    slot_ok: np.ndarray,
+    dslot_ok: np.ndarray,
+    has_slots: np.ndarray,
+    has_dgids: np.ndarray,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, int]:
+    """A chunk's blocked bulk rows summed per key (group, reason,
+    column), over the bulk region's columns (``sizes``: each group's
+    rows, in order). A FLOW row of a group with flow slots keys on its
+    first failing column of ``slot_ok``, a DEGRADE row of a group with
+    breakers on its first of ``dslot_ok``; every other row, and such a
+    row with no failing column (a speculative block the device passed),
+    keys on the unattributed column ``unattr``, after every slot.
+
+    Returns (group, reason, column, acquire sum) per key that holds a
+    row, in key order — groups in submit order, reasons ascending,
+    columns ascending — and ``unattr``. Elementwise passes and a dense
+    ``bincount``, no sort and no per-group call: O(rows) numpy for any
+    number of groups. The float sums are exact: a chunk's int32 weights
+    sum far below 2**53."""
+    unattr = max(slot_ok.shape[1], dslot_ok.shape[1])
+    blocked = ~admitted
+    if not blocked.any():
+        e = np.zeros(0, dtype=np.int64)
+        return e, e, e, e, unattr
+    col = np.full(admitted.size, unattr, dtype=np.int32)
+    for code, has, ok in (
+        (E.BLOCK_FLOW, has_slots, slot_ok),
+        (E.BLOCK_DEGRADE, has_dgids, dslot_ok),
+    ):
+        attr = blocked & (reason == code) & np.repeat(has, sizes)
+        if attr.any():
+            # Last column first, so the first failing column wins.
+            for j in range(ok.shape[1] - 1, -1, -1):
+                col = np.where(attr & ~ok[:, j], j, col)
+    big = np.iinfo(reason.dtype).max
+    r_lo = int(np.min(reason, where=blocked, initial=big))
+    nr = int(np.max(reason, where=blocked, initial=r_lo)) - r_lo + 1
+    ncol = unattr + 1
+    n_keys = sizes.size * nr * ncol
+    key = np.repeat(np.arange(0, n_keys, nr * ncol, dtype=np.intp), sizes)
+    key += (reason - r_lo) * ncol
+    key += col
+    # Admitted rows count in one spare bin past the keys.
+    key = np.where(blocked, key, n_keys)
+    present = np.flatnonzero(np.bincount(key, minlength=n_keys + 1)[:n_keys])
+    weight = np.bincount(key, weights=acquire)[present].astype(np.int64)
+    grp_r, c = np.divmod(present, ncol)
+    g, ri = np.divmod(grp_r, nr)
+    return g, ri + r_lo, c, weight, unattr
 
 
 def release_cluster_tokens(tokens: Sequence[Tuple[object, int]]) -> None:
@@ -3766,7 +3826,7 @@ class Engine:
                 seq, now_host, findex, dindex, pindex
             )
 
-        def _fill(got: _ChunkResults) -> List[tuple]:
+        def _fill(got: _ChunkResults) -> Tuple[List[tuple], int]:
             if ckpt_meta is not None:
                 fo.store_checkpoint(ckpt_meta, got.ckpt)
             res = self._fill_results(
@@ -4048,22 +4108,24 @@ class Engine:
         self.telemetry.fold_blocked_topk(pairs)
 
     def _fold_blocked_recount(
-        self, entries: List[_EntryOp], bulk: Sequence[BulkOp]
+        self,
+        entries: List[_EntryOp],
+        bulk_blocked: Sequence[Tuple[str, int]],
     ) -> None:
         """Host-side exact recount of one chunk's blocked weight per
         resource, folded into the telemetry sketch — the fallback for
         flush paths whose kernel lacks the device top-K fold (the
-        sharded mesh flush). Verdicts must already be filled."""
+        sharded mesh flush). Verdicts must already be filled;
+        ``bulk_blocked`` is each bulk group's (resource, blocked
+        weight)."""
         agg: Dict[str, int] = {}
         for op in entries:
             v = op._verdict
             if v is not None and not v.admitted:
                 agg[op.resource] = agg.get(op.resource, 0) + op.acquire
-        for g in bulk:
-            if g._admitted is not None:
-                w = int(g.acquire[~g._admitted].sum())
-                if w:
-                    agg[g.resource] = agg.get(g.resource, 0) + w
+        for resource, w in bulk_blocked:
+            if w:
+                agg[resource] = agg.get(resource, 0) + w
         self.telemetry.fold_blocked_topk(
             sorted(agg.items(), key=lambda kv: kv[1], reverse=True)[
                 : self._blk_topk_k
@@ -4084,11 +4146,13 @@ class Engine:
         kd: int,
         breaker_tag: Optional[Tuple[int, int]] = None,
         flush_seq: int = -1,
-    ) -> List[tuple]:
+    ) -> Tuple[List[tuple], int]:
         """Verdict fill for one dispatched chunk from its ALREADY
         FETCHED results; returns the chunk's blocked-verdict block-log
-        items. ``breaker_tag`` is the (epoch, seq) of ``got.breaker``.
-        Runs from the chunk record's settle (``_PendingChunk.settle``)."""
+        items and the number of bulk groups left on the speculative
+        reconcile path. ``breaker_tag`` is the (epoch, seq) of
+        ``got.breaker``. Runs from the chunk record's settle
+        (``_PendingChunk.settle``)."""
         admitted, reason, slot_ok = got.admitted, got.reason, got.slot_ok
         wait_ms, sys_type, dslot_ok = got.wait_ms, got.sys_type, got.dslot_ok
         if got.blk is not None:
@@ -4209,54 +4273,75 @@ class Engine:
                 op.trace = None
         if serve_acc:
             self.resource_metrics.note_serves_batch(serve_acc)
-        off_b = len(entries)
-        bulk_slices: List[Tuple[BulkOp, slice]] = []
-        for g in bulk:
-            sl = slice(off_b, off_b + g.n)
-            bulk_slices.append((g, sl))
+        # Bulk groups: one columnar pass over the chunk's bulk rows
+        # [lo, hi). One writable copy of each verdict column; every
+        # device-decided group's arrays are disjoint views of it, so a
+        # caller writing into one group's array touches no other's.
+        lo = len(entries)
+        sizes = np.array([g.n for g in bulk], dtype=np.int64)
+        ends = np.cumsum(sizes)
+        hi = lo + int(sizes.sum())
+        adm_c = np.array(admitted[lo:hi])
+        rsn_c = np.array(reason[lo:hi], dtype=np.int32)
+        wait_c = np.array(wait_ms[lo:hi])
+        n_spec = 0
+        # Traced groups' rows of the caller-visible columns, recorded in
+        # one call once the columns hold every group's verdicts.
+        traced: List[tuple] = []
+        for g, a, b in zip(bulk, (ends - sizes).tolist(), ends.tolist()):
             if spec_tier is not None and g.spec_admitted is not None:
                 # Speculatively-decided group: reconcile against the
                 # settled device arrays; the caller-visible results
                 # stay the speculative ones (see the singles branch).
                 spec_tier.reconcile_bulk(
-                    g,
-                    np.array(admitted[sl]),
-                    np.array(reason[sl], dtype=np.int32),
-                    dev_slot_ok=np.asarray(slot_ok[sl]),
-                    dev_sys_type=np.asarray(sys_type[sl]),
+                    g, adm_c[a:b].copy(), rsn_c[a:b].copy(),
+                    dev_slot_ok=np.asarray(slot_ok[lo + a:lo + b]),
+                    dev_sys_type=np.asarray(sys_type[lo + a:lo + b]),
                 )
+                # The block log and the trace read the caller-visible
+                # verdicts.
+                adm_c[a:b] = g._admitted
+                rsn_c[a:b] = g._reason
                 g._pending = None
                 if g.trace is not None:
-                    tracer.record_bulk(
-                        g.trace, g.resource, g.origin, g.context_name,
-                        g._admitted, g._reason, flush_seq, trace_end,
-                        degraded=g.spec_degraded,
-                        provenance="speculative",
-                    )
+                    traced.append((a, b, g.trace, g.resource, g.origin,
+                                   g.context_name, g.spec_degraded,
+                                   "speculative"))
                     g.trace = None
-                off_b += g.n
+                n_spec += 1
                 continue
-            g.admitted = np.array(admitted[sl])
-            reasons = np.array(reason[sl], dtype=np.int32)
+            g._admitted = adm = adm_c[a:b]
+            g._reason = reasons = rsn_c[a:b]
             if g.custom_veto_mask is not None:
-                reasons[~g._admitted & g.custom_veto_mask] = E.BLOCK_CUSTOM
-            g.reason = reasons
-            g.wait_ms = np.array(wait_ms[sl])
+                reasons[~adm & g.custom_veto_mask] = E.BLOCK_CUSTOM
+            g._wait_ms = wait_c[a:b]
             g._pending = None  # drop the chunk backref once filled
             if g.trace is not None:
-                tracer.record_bulk(
-                    g.trace, g.resource, g.origin, g.context_name,
-                    g._admitted, reasons, flush_seq, trace_end,
-                )
+                traced.append((a, b, g.trace, g.resource, g.origin,
+                               g.context_name, False, ""))
                 g.trace = None
-            off_b += g.n
+        tracer.record_bulk_groups(traced, adm_c, rsn_c, flush_seq, trace_end)
+        acq_c = np.concatenate(
+            [g.acquire for g in bulk] or [np.zeros(0, dtype=np.int32)]
+        )
+        t_grp, t_rsn, t_col, t_w, unattr = _tally_blocked(
+            adm_c, rsn_c, acq_c, sizes,
+            np.asarray(slot_ok)[lo:hi], np.asarray(dslot_ok)[lo:hi],
+            np.array([bool(g.slots) for g in bulk], dtype=bool),
+            np.array([bool(g.d_gids) for g in bulk], dtype=bool),
+        )
 
         if got.blk is None and self._blk_topk_k > 0:
             # Kernel paths without the device fold (the sharded mesh
             # flush) still feed the blocked top-K: recount blocked
             # weight host-side from the verdicts just filled — exact,
             # and the data is already on the host.
-            self._fold_blocked_recount(entries, [g for g, _ in bulk_slices])
+            grp_w = np.bincount(t_grp, weights=t_w, minlength=len(bulk))
+            self._fold_blocked_recount(
+                entries,
+                [(g.resource, w) for g, w in
+                 zip(bulk, grp_w.astype(np.int64).tolist())],
+            )
 
         # ---- block log + metric-extension callbacks ----
         # LogSlot (order −8000) writing sentinel-block.log, and the
@@ -4289,74 +4374,57 @@ class Engine:
                     MetricExtensionProvider.on_blocked(
                         op.resource, op.acquire, op.origin, err, op.args
                     )
-        # Bulk groups: aggregated block-log items (the block log counts
-        # per (resource, exc, limitApp, origin) key, so summed items per
-        # key are exact) and aggregated extension calls. Flow/degrade
-        # blocks attribute the blocking rule's limitApp like the singles
-        # path — first failing slot per entry, grouped by slot.
-        for g, sl in bulk_slices:
-            if g.admitted is None:
-                continue
-            blocked = ~g.admitted
-
-            def _slot_attributed(sel, bad, rule_of_col) -> List[Tuple[str, int]]:
-                """(limit_app, count) aggregates from a per-row × slot
-                failure matrix. A speculatively-blocked row the DEVICE
-                passed has no failing slot (mirror and device picked
-                different individuals — structural under drift); argmax
-                on its all-False row would misattribute it to slot 0's
-                rule, so such rows aggregate under "default" instead."""
-                has_bad = bad.any(axis=1)
-                first_bad = np.argmax(bad, axis=1)
-                out_items = []
-                for j in np.unique(first_bad[has_bad]):
-                    rule = rule_of_col(int(j))
-                    la = getattr(rule, "limit_app", None) or "default"
-                    out_items.append((
-                        la,
-                        int(g.acquire[sel][has_bad & (first_bad == j)].sum()),
-                    ))
-                n_unattr = int(g.acquire[sel][~has_bad].sum())
-                if n_unattr:
-                    out_items.append(("default", n_unattr))
-                return out_items
-
-            def _bulk_block_items(r: int) -> List[Tuple[str, int]]:
-                """(limit_app, count) aggregates for reason ``r``."""
-                sel = blocked & (g.reason == r)
-                if r == E.BLOCK_FLOW and g.slots:
-                    return _slot_attributed(
-                        sel, ~slot_ok[sl][sel],
-                        lambda j: findex.rule_of_gid(g.slots[j][0])
-                        if j < len(g.slots) else None,
-                    )
-                if r == E.BLOCK_DEGRADE and g.d_gids:
-                    return _slot_attributed(
-                        sel, ~dslot_ok[sl][sel],
-                        lambda j: dindex.rule_of_gid(g.d_gids[j])
-                        if j < len(g.d_gids) else None,
-                    )
+        # Bulk groups: one block-log item per key of the tally (the
+        # block log counts per (resource, exc, limitApp, origin) key, so
+        # summed items per key are exact). Flow/degrade blocks attribute
+        # the blocking rule's limitApp like the singles path — first
+        # failing slot per entry. A flow/degrade row with no failing
+        # slot is a speculative block the DEVICE passed (mirror and
+        # device picked different individuals — structural under
+        # drift): it sums under "default", logged only when nonzero.
+        for gi, r, c, w in zip(
+            t_grp.tolist(), t_rsn.tolist(), t_col.tolist(), t_w.tolist()
+        ):
+            g = bulk[gi]
+            rule = None
+            if c == unattr:
+                if not w and (
+                    (r == E.BLOCK_FLOW and g.slots)
+                    or (r == E.BLOCK_DEGRADE and g.d_gids)
+                ):
+                    continue
                 if r == E.BLOCK_AUTHORITY:
                     rule = auth_rules.get(g.resource)
-                    la = getattr(rule, "limit_app", None) or "default"
-                    return [(la, int(g.acquire[sel].sum()))]
-                return [("default", int(g.acquire[sel].sum()))]
-
-            if blocked.any():
-                for r in np.unique(g.reason[blocked]):
-                    exc_name = E.exc_name_for_code(int(r))
-                    for la, cnt in _bulk_block_items(int(r)):
-                        blocked_items.append((g.resource, exc_name, la, g.origin, cnt))
-                    if exts:
-                        err = E.error_for_verdict(int(r), g.resource)
-                        MetricExtensionProvider.on_blocked(
-                            g.resource, int(g.acquire[blocked & (g.reason == r)].sum()),
-                            g.origin, err, (),
-                        )
-            if exts and g.admitted.any():
-                MetricExtensionProvider.on_pass(
-                    g.resource, int(g.acquire[g.admitted].sum()), ()
-                )
+            elif r == E.BLOCK_FLOW:
+                if c < len(g.slots):
+                    rule = findex.rule_of_gid(g.slots[c][0])
+            elif c < len(g.d_gids):
+                rule = dindex.rule_of_gid(g.d_gids[c])
+            blocked_items.append((
+                g.resource, E.exc_name_for_code(r),
+                getattr(rule, "limit_app", None) or "default", g.origin, w,
+            ))
+        if exts and bulk:
+            # Aggregated extension calls, per group in submit order: its
+            # blocks per reason ascending (the tally's order), then its
+            # passes.
+            blocked_w: Dict[int, Dict[int, int]] = {}
+            for gi, r, w in zip(t_grp.tolist(), t_rsn.tolist(), t_w.tolist()):
+                per_r = blocked_w.setdefault(gi, {})
+                per_r[r] = per_r.get(r, 0) + w
+            adm_g = np.repeat(np.arange(len(bulk)), sizes)[adm_c]
+            n_pass = np.bincount(adm_g, minlength=len(bulk)).tolist()
+            w_pass = np.bincount(
+                adm_g, weights=acq_c[adm_c], minlength=len(bulk)
+            ).astype(np.int64).tolist()
+            for gi, g in enumerate(bulk):
+                for r, w in blocked_w.get(gi, {}).items():
+                    MetricExtensionProvider.on_blocked(
+                        g.resource, w, g.origin,
+                        E.error_for_verdict(r, g.resource), (),
+                    )
+                if n_pass[gi]:
+                    MetricExtensionProvider.on_pass(g.resource, w_pass[gi], ())
         if exts:
             for x in exits:
                 if x.resource is not None and x.thr < 0:
@@ -4379,7 +4447,7 @@ class Engine:
                         gx.resource, _weighted_rt(gx), int(gx.count.sum()),
                         int(gx.err.sum()),
                     )
-        return blocked_items
+        return blocked_items, n_spec
 
     def _encode_shaping(
         self, entries: List[_EntryOp], bulk: List[BulkOp], k: int, findex: FlowIndex

@@ -512,7 +512,11 @@ class TestProfilerPhases:
                        for p in mine), (name, parent)
         shaping = next(e for e in mine if e[0] == "sentinel.encode.shaping")
         assert shaping[3]["items"] == 16 and shaping[3]["padded"] == 16
-        assert next(e for e in mine if e[0] == "sentinel.fill")[3]["groups"] == 2
+        fill = next(e for e in mine if e[0] == "sentinel.fill")[3]
+        assert (fill["groups"], fill["spec_groups"]) == (2, 0)
+        # One block-log item per (group, reason, blocking rule): the paced
+        # group's flow blocks and the param group's blocks.
+        assert fill["block_items"] == engine.telemetry.spans()[-1].block_items == 2
         # Accounting runs: the two groups over 32 entries, one exit group
         # over 8 exits; tables this small are as long as their side.
         staging = next(e for e in mine if e[0] == "sentinel.encode.staging")[3]
@@ -533,6 +537,8 @@ class TestProfilerPhases:
             ), name
         assert span.settle_ms == pytest.approx(span.fetch_ms + span.fill_ms)
         assert span.shaping_items == 16 and span.groups == 2
+        assert (span.spec_groups, span.block_items) == (0, 2)
+        assert span.as_dict()["block_items"] == 2
         view = engine.last_flush_host_ms
         assert view["kernel_ms"] == pytest.approx(span.dispatch_ms + span.fetch_ms)
         assert view["fill_ms"] == pytest.approx(span.fill_ms)
